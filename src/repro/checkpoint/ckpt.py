@@ -13,8 +13,8 @@ device_puts every leaf with the NamedSharding derived from the current mesh
 and the model's logical axis rules. A job restarted on a different pod
 count resumes exactly (the data pipeline is a pure function of the restored
 step). On a real multi-host cluster the same layout is written once per
-leaf-shard by the host owning it; this container is single-process, so the
-full-array path is the live one (noted in DESIGN.md).
+leaf-shard by the host owning it; the trainer here is single-process, so
+the full-array path is the live one.
 """
 
 from __future__ import annotations
@@ -30,8 +30,7 @@ import numpy as np
 
 
 def _flatten_with_paths(tree):
-    from repro.compat import tree_flatten_with_path
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     items = []
     for path, leaf in flat:
         key = "/".join(

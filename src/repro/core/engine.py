@@ -15,9 +15,9 @@ Bernoulli(P_X · P_Y) exactly. The simulator reproduces the *sequence*
 (per-cell I_c spread, §IV-B) act on each pulse separately, exactly as the
 paper's Monte-Carlo does.
 
-Entropy: the container's TPU-kernel PRNG is unavailable on CPU interpret
-mode, so random draws are counter-based threefry via ``jax.random`` — the
-statistical contract (iid uniforms per cell per pulse) is identical to the
+Entropy: the TPU's in-kernel PRNG has no CPU interpreter path, so random
+draws are counter-based threefry via ``jax.random`` — the statistical
+contract (iid uniforms per cell per pulse) is identical to the
 thermal randomness the device supplies.
 """
 

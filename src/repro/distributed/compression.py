@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_compat
-
 
 def _quantize(g):
     scale = jnp.maximum(jnp.max(jnp.abs(g)), 1e-20) / 127.0
@@ -77,17 +75,12 @@ def compressed_grads(grad_fn, mesh, *, has_aux: bool = False):
         return loss, grads_c, new_ef
 
     def wrapped(params, batch, ef):
-        return _shard_map(
+        return jax.shard_map(
             pod_local,
             mesh=mesh,
             in_specs=(P(), P("pod"), P("pod")),
             out_specs=(P(), P(), P("pod")),
-            manual_axes={"pod"},
+            axis_names={"pod"},
         )(params, batch, ef)
 
     return wrapped
-
-
-# Version-compat shard_map now lives in repro.compat (it gained a second
-# consumer: the mesh-sharded SC substrate in repro.sc.sharded).
-_shard_map = shard_map_compat

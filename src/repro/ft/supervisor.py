@@ -3,7 +3,7 @@
 On a real cluster each worker process runs the train loop and a sidecar
 heartbeat; the supervisor (one per job) watches heartbeats, and on a missed
 deadline kills the step, re-forms the mesh from the survivors (elastic), and
-restores from the last complete checkpoint. This container is
+restores from the last complete checkpoint. The trainer here is
 single-process, so the same control flow runs in-process: failures are
 raised as :class:`WorkerFailure` (tests inject them at chosen steps), and
 recovery = restore + replay. Determinism makes recovery exact: the data

@@ -6,10 +6,13 @@ as separate XLA ops, materializing every row's full gathered cache view
 per tick.  This kernel collapses the sequence into ONE ``pallas_call``
 per layer, flash-attention style:
 
-* **block-table gather in-kernel** — the K/V block pools ride in whole
-  (one kv-head slice per grid step) and each grid step loads just the
-  one ``block_size`` page its block-table entry names, so the
-  (b, nb*bs, kv, hd) gathered view is never materialized;
+* **block-table gather in-kernel** — the block table and fill lengths
+  ride in as scalar-prefetch operands (SMEM), and the K/V ``index_map``
+  reads the table, so grid step ``(row, kv head, q tile, j)`` DMAs just
+  the one ``(block_size, head_dim)`` page slice that table entry ``j``
+  names for that kv head — the pool is laid out ``(P, kv, bs, hd)`` so
+  that slice is contiguous — and the (b, nb*bs, kv, hd) gathered view is
+  never materialized;
 * **online softmax** — a running (max, denominator, accumulator) triple
   lives in VMEM scratch across the KV-block grid axis (the same
   recurrence as ``models/attention.py:blockwise_attention``), so peak
@@ -38,28 +41,32 @@ host-side twin (same jnp body, bit equality by construction) the
 invariance tests pin against.
 
 Tile selection (``block_q`` rows per grid step, ``lane_words`` RNG words
-per Horner sweep) routes through ``sc/autotune.py``'s versioned cache
-under the ``attn`` kernel kind, with a deterministic heuristic fallback.
-Tiling never changes bits: each logit's pop-count total is computed
-whole within one grid step from globally-addressed counters.
-
-Like every Pallas kernel in this repo the launch defaults to
-``interpret=True`` (CPU correctness harness); real TPUs flip it off.
+per unrolled step) routes through ``sc/autotune.py``'s versioned cache
+under the ``attn`` kernel kind, with a deterministic heuristic fallback;
+``block_q`` rounds up to a sublane multiple.  Tiling never changes bits:
+each logit's pop-count total is computed whole within one grid step from
+globally-addressed counters.  The kernels run compiled on a TPU and in
+the Pallas interpreter on the CPU (:func:`repro.kernels.interpret_mode`).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sc_fused import encode_fx16
-from repro.kernels.sc_mul import LANE_BITS, NSLICES, popcount32
+from repro import kernels
+from repro.kernels.sc_fused import encode_fx16, word_counts
+from repro.kernels.sc_mul import LANE_BITS
 from repro.sc import autotune, ctr_rng
 
 NEG_INF = -1e30  # matches models/attention.py
 _DENOM_GUARD = 1e-30  # matches blockwise_attention's divide guard
 _SCALE_GUARD = 1e-30  # matches sc/encoding.py's max-abs clamp
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _scale(hd: int):
@@ -90,18 +97,30 @@ def _online_softmax_step(logits, v_blk, m_ref, d_ref, a_ref):
     p = jnp.exp(logits - m_new)
     m_ref[...] = m_new
     d_ref[...] = d_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    a_ref[...] = a_ref[...] * alpha + jnp.dot(p, v_blk)
+    a_ref[...] = a_ref[...] * alpha + jnp.dot(
+        p, v_blk, precision=_HIGHEST, preferred_element_type=jnp.float32)
 
 
-def _mask(logits, len_ref, *, j, sc, block_size, block_q):
+def _mask(logits, length, *, j, sc, block_size, block_q):
     """``chunk_decode_attention``'s predicate: t <= lengths[r] + i."""
     shape = (block_q, block_size)
     t_idx = j * block_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     row = pl.program_id(2) * block_q + jax.lax.broadcasted_iota(
         jnp.int32, shape, 0
     )
-    q_pos = len_ref[0, 0] + row % sc
+    q_pos = length + row % sc
     return jnp.where(t_idx <= q_pos, logits, NEG_INF)
+
+
+def _init_carry(m_ref, d_ref, a_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    d_ref[...] = jnp.zeros_like(d_ref)
+    a_ref[...] = jnp.zeros_like(a_ref)
+
+
+def _emit(o_ref, d_ref, a_ref):
+    out = a_ref[...] / jnp.maximum(d_ref[...], _DENOM_GUARD)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def _paged_attn_kernel(
@@ -120,82 +139,85 @@ def _paged_attn_kernel(
     nb: int,
     block_q: int,
 ):
-    """Deterministic fused step: gather one page, QK^T, online softmax."""
+    """Deterministic fused step: one page, QK^T, online softmax."""
+    del bt_ref  # consumed by the K/V index maps
     j = pl.program_id(3)
 
     @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        d_ref[...] = jnp.zeros_like(d_ref)
-        a_ref[...] = jnp.zeros_like(a_ref)
+    def _():
+        _init_carry(m_ref, d_ref, a_ref)
 
-    page = bt_ref[0, 0]
-    k_blk = k_ref[page][:, 0, :].astype(jnp.float32)  # (bs, hd)
-    v_blk = v_ref[page][:, 0, :].astype(jnp.float32)  # (bs, hd)
+    k_blk = k_ref[0, 0].astype(jnp.float32)  # (bs, hd)
+    v_blk = v_ref[0, 0].astype(jnp.float32)  # (bs, hd)
     q_blk = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
-    logits = jnp.dot(q_blk, k_blk.T) * _scale(q_blk.shape[-1])
-    logits = _mask(
-        logits, len_ref, j=j, sc=sc, block_size=block_size, block_q=block_q
-    )
+    logits = jax.lax.dot_general(
+        q_blk, k_blk, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32) * _scale(q_blk.shape[-1])
+    logits = _mask(logits, len_ref[pl.program_id(0)], j=j, sc=sc,
+                   block_size=block_size, block_q=block_q)
     _online_softmax_step(logits, v_blk, m_ref, d_ref, a_ref)
 
     @pl.when(j == nb - 1)
-    def _emit():
-        out = a_ref[...] / jnp.maximum(d_ref[...], _DENOM_GUARD)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+    def _():
+        _emit(o_ref, d_ref, a_ref)
 
 
-def _sc_counts(keys4, fxq, fxk, c0, *, nbit: int, lane_words: int):
-    """Signed-magnitude pop-count core shared by kernel and host twin.
+@functools.partial(jax.jit, static_argnames=(
+    "n_heads", "nbit", "levels", "quantize", "lane"))
+def _sc_logits(q_blk, k_blk, keys4, t_abs, head, *, n_heads, nbit,
+               levels, quantize, lane):
+    """SC-sampled QK^T logits tile from exact q/k tiles (f32 in/out).
 
-    keys4: (bq, 4) per-row operand key words; fxq: (bq, hd) fx16 query
-    magnitudes; fxk: (bs, hd) fx16 key magnitudes; c0: (bq, bs, hd)
-    uint32 product counters.  Returns int32 (bq, bs, hd) pop-count
-    totals.  Integer accumulation over words is associative, so
-    ``lane_words`` can never change the result.
+    q_blk: (bq, hd) query rows; k_blk: (T, hd) cache rows at absolute
+    positions ``t_abs`` (1, T) int32; keys4: (bq, 4) uint32 per-row
+    operand key words; head: (bq, 1) int32 flat query head per row.
+    Returns (bq, T) pre-mask logits.  Walks the T cache rows one at a
+    time so every vector stays (bq, hd) with rows on sublanes; row
+    selection is an exact masked sum.  The pop-count core
+    (``sc_fused.word_counts``) sums integers, so ``lane`` can never
+    change the result.
     """
+    bq, hd = q_blk.shape
+    n_kv = k_blk.shape[0]
     nwords = nbit // LANE_BITS
-    kq0 = keys4[:, 0][:, None, None, None]
-    kq1 = keys4[:, 1][:, None, None, None]
-    kk0 = keys4[:, 2][:, None, None, None]
-    kk1 = keys4[:, 3][:, None, None, None]
-    c0_4 = c0[..., None]
-    pq4 = fxq[:, None, :, None]
-    pk4 = fxk[None, :, :, None]
-    counts = jnp.zeros(c0.shape, jnp.int32)
-    for w0 in range(0, nwords, lane_words):
-        wc = min(lane_words, nwords - w0)
-        widx = jnp.uint32(w0) + jax.lax.broadcasted_iota(
-            jnp.uint32, (wc,), 0
-        )
-        tq = jnp.zeros(c0.shape + (wc,), jnp.uint32)
-        tk = jnp.zeros(c0.shape + (wc,), jnp.uint32)
-        for s in range(NSLICES):  # LSB -> MSB Horner bit-ladder
-            c1 = (jnp.uint32(s * nwords) + widx)[None, None, None, :]
-            uq = ctr_rng.threefry2x32(kq0, kq1, c0_4, c1)[0]
-            uk = ctr_rng.threefry2x32(kk0, kk1, c0_4, c1)[0]
-            mq = jnp.uint32(0) - ((pq4 >> jnp.uint32(s)) & jnp.uint32(1))
-            mk = jnp.uint32(0) - ((pk4 >> jnp.uint32(s)) & jnp.uint32(1))
-            tq = (mq & (uq | tq)) | (~mq & (uq & tq))
-            tk = (mk & (uk | tk)) | (~mk & (uk & tk))
-        survived = tq & tk  # two-pulse AND (paper Fig. 5)
-        counts += jnp.sum(popcount32(survived).astype(jnp.int32), axis=-1)
-    return counts
-
-
-def _sc_logits(q_blk, k_blk, keys4, c0, *, nbit, levels, quantize, lane):
-    """SC-sampled QK^T logits tile from exact q/k tiles (f32 in/out)."""
-    scq = jnp.maximum(jnp.max(jnp.abs(q_blk), axis=1), _SCALE_GUARD)
-    sck = jnp.maximum(jnp.max(jnp.abs(k_blk), axis=1), _SCALE_GUARD)
-    fxq = encode_fx16(jnp.abs(q_blk) / scq[:, None], levels, quantize)
-    fxk = encode_fx16(jnp.abs(k_blk) / sck[:, None], levels, quantize)
+    scq = jnp.maximum(jnp.max(jnp.abs(q_blk), axis=1, keepdims=True),
+                      _SCALE_GUARD)
+    sck = jnp.maximum(jnp.max(jnp.abs(k_blk), axis=1, keepdims=True),
+                      _SCALE_GUARD)
+    fxq = encode_fx16(jnp.abs(q_blk) / scq, levels, quantize)
+    fxk = jax.lax.bitcast_convert_type(
+        encode_fx16(jnp.abs(k_blk) / sck, levels, quantize), jnp.int32)
     sgq = jnp.sign(q_blk).astype(jnp.int32)
     sgk = jnp.sign(k_blk).astype(jnp.int32)
-    counts = _sc_counts(keys4, fxq, fxk, c0, nbit=nbit, lane_words=lane)
-    signed = sgq[:, None, :] * sgk[None, :, :] * counts
-    total = jnp.sum(signed, axis=-1).astype(jnp.float32)  # (bq, bs)
-    est = total / jnp.float32(nbit) * scq[:, None] * sck[None, :]
-    return est * _scale(q_blk.shape[-1])
+    kq = (keys4[:, 0:1], keys4[:, 1:2])
+    kk = (keys4[:, 2:3], keys4[:, 3:4])
+    d_idx = jax.lax.broadcasted_iota(jnp.int32, (1, hd), 1)
+    kv_row = jax.lax.broadcasted_iota(jnp.int32, (n_kv, hd), 0)
+    kv_row1 = jax.lax.broadcasted_iota(jnp.int32, (n_kv, 1), 0)
+    kv_lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_kv), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (bq, n_kv), 1)
+
+    def column(t, logits):
+        sel = kv_row == t
+        pk = jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(sel, fxk, 0), axis=0, keepdims=True),
+            jnp.uint32)                                      # (1, hd)
+        sk = jnp.sum(jnp.where(sel, sgk, 0), axis=0, keepdims=True)
+        sck_t = jnp.sum(jnp.where(kv_row1 == t, sck, 0.0), axis=0,
+                        keepdims=True)                       # (1, 1)
+        ta = jnp.sum(jnp.where(kv_lane == t, t_abs, 0), axis=1,
+                     keepdims=True)                          # (1, 1)
+        c0 = jax.lax.bitcast_convert_type(
+            (ta * n_heads + head) * hd + d_idx, jnp.uint32)  # (bq, hd)
+        counts = word_counts(
+            kq, kk, c0, fxq, jnp.broadcast_to(pk, (bq, hd)),
+            nwords=nwords, lane_words=lane)
+        total = jnp.sum(sgq * sk * counts, axis=1, keepdims=True)
+        est = total.astype(jnp.float32) / jnp.float32(nbit) * scq * sck_t
+        return jnp.where(out_lane == t, est * _scale(hd), logits)
+
+    return jax.lax.fori_loop(0, n_kv, column,
+                             jnp.zeros((bq, n_kv), jnp.float32))
 
 
 def _paged_attn_sc_kernel(
@@ -224,73 +246,34 @@ def _paged_attn_sc_kernel(
     """SC-sampled fused step: same gather and online softmax, but the
     QK^T tile is the paper's stochastic MUL drawn from each query
     token's pinned counter stream."""
+    del bt_ref  # consumed by the K/V index maps
     j = pl.program_id(3)
 
     @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        d_ref[...] = jnp.zeros_like(d_ref)
-        a_ref[...] = jnp.zeros_like(a_ref)
+    def _():
+        _init_carry(m_ref, d_ref, a_ref)
 
-    page = bt_ref[0, 0]
-    k_blk = k_ref[page][:, 0, :].astype(jnp.float32)  # (bs, hd)
-    v_blk = v_ref[page][:, 0, :].astype(jnp.float32)  # (bs, hd)
+    k_blk = k_ref[0, 0].astype(jnp.float32)  # (bs, hd)
+    v_blk = v_ref[0, 0].astype(jnp.float32)  # (bs, hd)
     q_blk = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
-    hd = q_blk.shape[-1]
 
-    # global (query row, kv position, lane) -> pinned product counter:
     # the query's identity rides in its KEY, the kv side in the counter,
     # so the draw survives any batch/chunk/block-size/eviction reshuffle
-    shape3 = (block_q, block_size, hd)
-    t_abs = jnp.uint32(j * block_size) + jax.lax.broadcasted_iota(
-        jnp.uint32, shape3, 1
-    )
-    d_idx = jax.lax.broadcasted_iota(jnp.uint32, shape3, 2)
-    row = jnp.uint32(pl.program_id(2) * block_q) + jax.lax.broadcasted_iota(
-        jnp.uint32, shape3, 0
-    )
-    head = (
-        jnp.uint32(pl.program_id(1)) * jnp.uint32(group)
-        + row // jnp.uint32(sc)
-    )
-    c0 = (t_abs * jnp.uint32(n_heads) + head) * jnp.uint32(hd) + d_idx
-
+    row = pl.program_id(2) * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    head = pl.program_id(1) * group + row // sc
+    t_abs = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
     logits = _sc_logits(
-        q_blk,
-        k_blk,
-        keys_ref[0],
-        c0,
-        nbit=nbit,
-        levels=levels,
-        quantize=quantize,
-        lane=lane_words,
-    )
-    logits = _mask(
-        logits, len_ref, j=j, sc=sc, block_size=block_size, block_q=block_q
-    )
+        q_blk, k_blk, keys_ref[0], t_abs, head, n_heads=n_heads,
+        nbit=nbit, levels=levels, quantize=quantize, lane=lane_words)
+    logits = _mask(logits, len_ref[pl.program_id(0)], j=j, sc=sc,
+                   block_size=block_size, block_q=block_q)
     _online_softmax_step(logits, v_blk, m_ref, d_ref, a_ref)
 
     @pl.when(j == nb - 1)
-    def _emit():
-        out = a_ref[...] / jnp.maximum(d_ref[...], _DENOM_GUARD)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, jnp.float32)
-
-
-def _tpu_params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-    )
+    def _():
+        _emit(o_ref, d_ref, a_ref)
 
 
 def _rows_layout(q, kvh: int):
@@ -314,50 +297,58 @@ def _rows_unlayout(out, *, sc: int, h: int):
     return out.reshape(b, sc, h, hd)
 
 
-def _launch(
-    kernel,
-    *,
-    grid,
-    block_q,
-    hd,
-    num_pages,
-    bs,
-    b,
-    kvh,
-    rows_p,
-    extra_specs,
-    operands,
-    interpret,
-):
+def _launch(kernel, *, q_rows, block_table, lengths, extra, k_pages,
+            v_pages, block_q):
+    """One fused-attention ``pallas_call`` over grid (b, kv, q tiles, nb).
+
+    q_rows: (b, kvh, rows_p, hd) kernel rows; extra: per-row operands of
+    shape (b, rows_p, w) blocked alongside the query tile.  The block
+    table (flattened) and lengths are scalar-prefetched; each K/V step
+    DMAs the (block_size, hd) slice of the page the table names.
+    """
+    b, kvh, rows_p, hd = q_rows.shape
+    _, _, bs, _ = k_pages.shape
+    nb = block_table.shape[1]
+
+    def page(i, h_, qi, j, bt, ln):
+        return (bt[i * nb + j], h_, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, 1, bs, hd), page)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, kvh, rows_p // block_q, nb),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, hd),
+                         lambda i, h_, qi, j, bt, ln: (i, h_, qi, 0)),
+            *[pl.BlockSpec((1, block_q, e.shape[-1]),
+                           lambda i, h_, qi, j, bt, ln: (i, qi, 0))
+              for e in extra],
+            kv_spec,
+            kv_spec,
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda i, h_, qi, j, bt, ln: (i, h_, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, h_, qi, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, h_, qi, j: (i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_q, hd), lambda i, h_, qi, j: (i, h_, qi, 0)
-            ),
-            *extra_specs,
-            pl.BlockSpec(
-                (num_pages, bs, 1, hd), lambda i, h_, qi, j: (0, 0, h_, 0)
-            ),
-            pl.BlockSpec(
-                (num_pages, bs, 1, hd), lambda i, h_, qi, j: (0, 0, h_, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, hd), lambda i, h_, qi, j: (i, h_, qi, 0)
-        ),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows_p, hd), jnp.float32),
-        scratch_shapes=[
-            _vmem((block_q, 1)),
-            _vmem((block_q, 1)),
-            _vmem((block_q, hd)),
-        ],
-        compiler_params=_tpu_params(),
-        interpret=interpret,
-    )(*operands)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=kernels.interpret_mode(),
+    )(block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      q_rows, *extra, k_pages, v_pages)
+
+
+def _row_tile(block_q: int) -> int:
+    """Query rows per grid step, rounded up to a sublane multiple."""
+    return -(-max(1, block_q) // 8) * 8
 
 
 def paged_attention_fused(
@@ -368,54 +359,34 @@ def paged_attention_fused(
     lengths,
     *,
     block_q: int = 0,
-    interpret: bool = True,
 ):
     """Fused paged attention, deterministic QK^T.
 
     q: (b, sc, h, hd) post-rope queries (chunk token i of row r sits at
     absolute position ``lengths[r] + i``, K/V already scattered);
-    k/v_pages: (P, bs, kvh, hd) block pools; block_table: (b, nb);
+    k/v_pages: (P, kvh, bs, hd) block pools; block_table: (b, nb);
     lengths: (b,) pre-chunk fill.  Returns (b, sc, h, hd) — the fused
     equivalent of ``chunk_decode_attention(q, paged_gather(k), ...)``.
     ``block_q = 0`` takes the row tile from the autotune cache
     (``attn`` kernel kind; heuristic on miss).
     """
-    import functools
-
     b, sc, h, hd = q.shape
-    num_pages, bs, kvh, _ = k_pages.shape
+    _, kvh, bs, _ = k_pages.shape
     nb = block_table.shape[1]
     rows = (h // kvh) * sc
     if block_q <= 0:
         block_q = autotune.get_attn_tile(rows, bs, hd, 0).block_q
+    block_q = _row_tile(block_q)
     qr = _rows_layout(q, kvh)
     pad = (-rows) % block_q
     if pad:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    rows_p = rows + pad
     kernel = functools.partial(
         _paged_attn_kernel, sc=sc, block_size=bs, nb=nb, block_q=block_q
     )
-    out = _launch(
-        kernel,
-        grid=(b, kvh, rows_p // block_q, nb),
-        block_q=block_q,
-        hd=hd,
-        num_pages=num_pages,
-        bs=bs,
-        b=b,
-        kvh=kvh,
-        rows_p=rows_p,
-        extra_specs=[],
-        operands=(
-            block_table.astype(jnp.int32),
-            lengths.astype(jnp.int32)[:, None],
-            qr,
-            k_pages,
-            v_pages,
-        ),
-        interpret=interpret,
-    )
+    out = _launch(kernel, q_rows=qr, block_table=block_table,
+                  lengths=lengths, extra=(), k_pages=k_pages,
+                  v_pages=v_pages, block_q=block_q)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 
@@ -432,7 +403,6 @@ def paged_attention_fused_sc(
     quantize: bool = True,
     block_q: int = 0,
     lane_words: int = 0,
-    interpret: bool = True,
 ):
     """Fused paged attention with the SC-sampled QK^T.
 
@@ -443,10 +413,8 @@ def paged_attention_fused_sc(
     :func:`paged_attention_fused`.  ``block_q`` / ``lane_words`` = 0
     take the ``attn`` autotune entry; the tiling never changes bits.
     """
-    import functools
-
     b, sc, h, hd = q.shape
-    num_pages, bs, kvh, _ = k_pages.shape
+    _, kvh, bs, _ = k_pages.shape
     nb = block_table.shape[1]
     g = h // kvh
     rows = g * sc
@@ -454,9 +422,13 @@ def paged_attention_fused_sc(
     tile = autotune.get_attn_tile(rows, bs, hd, nbit)
     if block_q <= 0:
         block_q = tile.block_q
+    block_q = _row_tile(block_q)
     if lane_words <= 0:
         lane_words = tile.lane_words
-    lane_words = min(lane_words, max(1, nbit // LANE_BITS))
+    nwords = nbit // LANE_BITS
+    lane_words = max(1, min(lane_words, nwords))
+    while nwords % lane_words:
+        lane_words -= 1
     qr = _rows_layout(q, kvh)
     keys4 = split_keys4(keys)  # (b, sc, 4)
     rowk = jnp.broadcast_to(keys4[:, None], (b, g, sc, 4)).reshape(b, rows, 4)
@@ -464,7 +436,6 @@ def paged_attention_fused_sc(
     if pad:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad), (0, 0)))
         rowk = jnp.pad(rowk, ((0, 0), (0, pad), (0, 0)))
-    rows_p = rows + pad
     kernel = functools.partial(
         _paged_attn_sc_kernel,
         sc=sc,
@@ -478,29 +449,9 @@ def paged_attention_fused_sc(
         quantize=quantize,
         lane_words=lane_words,
     )
-    out = _launch(
-        kernel,
-        grid=(b, kvh, rows_p // block_q, nb),
-        block_q=block_q,
-        hd=hd,
-        num_pages=num_pages,
-        bs=bs,
-        b=b,
-        kvh=kvh,
-        rows_p=rows_p,
-        extra_specs=[
-            pl.BlockSpec((1, block_q, 4), lambda i, h_, qi, j: (i, qi, 0)),
-        ],
-        operands=(
-            block_table.astype(jnp.int32),
-            lengths.astype(jnp.int32)[:, None],
-            qr,
-            rowk,
-            k_pages,
-            v_pages,
-        ),
-        interpret=interpret,
-    )
+    out = _launch(kernel, q_rows=qr, block_table=block_table,
+                  lengths=lengths, extra=(rowk,), k_pages=k_pages,
+                  v_pages=v_pages, block_q=block_q)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 
@@ -525,18 +476,14 @@ def sc_qk_logits_host(
     returned (T,) logits equal the kernel's pre-mask logits bit-for-bit
     by construction — the anchor the reproducibility tests pin.
     """
-    hd = q_row.shape[-1]
     keys4 = split_keys4(key[None])  # (1, 4)
-    t_abs = jnp.asarray(t_abs, jnp.uint32)
-    d_idx = jnp.arange(hd, dtype=jnp.uint32)
-    c0 = (
-        t_abs[None, :, None] * jnp.uint32(n_heads) + jnp.uint32(head)
-    ) * jnp.uint32(hd) + d_idx[None, None, :]
     logits = _sc_logits(
         q_row[None].astype(jnp.float32),
         k_rows.astype(jnp.float32),
         keys4,
-        c0,
+        jnp.asarray(t_abs, jnp.int32)[None],
+        jnp.full((1, 1), head, jnp.int32),
+        n_heads=n_heads,
         nbit=nbit,
         levels=1 << operand_bits,
         quantize=quantize,

@@ -29,6 +29,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro import kernels
 
 
 def _sc_mac_kernel(x_ref, w_ref, noise_ref, out_ref,
@@ -97,10 +100,9 @@ def _sc_mac_kernel_prng(seed_ref, x_ref, w_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("nbit", "block_m", "block_n", "block_k", "interpret"))
+    static_argnames=("nbit", "block_m", "block_n", "block_k"))
 def sc_mac_fused(x_signed_p, w_signed_p, noise, *, nbit: int = 1024,
-                 block_m: int = 128, block_n: int = 128, block_k: int = 512,
-                 interpret: bool = True):
+                 block_m: int = 128, block_n: int = 128, block_k: int = 512):
     """Fused SC matmul on pre-encoded signed probabilities.
 
     x_signed_p: (M, K) f32 in [-1, 1]; w_signed_p: (K, N) f32 in [-1, 1];
@@ -128,8 +130,8 @@ def sc_mac_fused(x_signed_p, w_signed_p, noise, *, nbit: int = 1024,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         # three f32 accumulators resident across the k loop
         scratch_shapes=[_vmem(bm, bn), _vmem(bm, bn), _vmem(bm, bn)],
-        compiler_params=_tpu_params(),
-        interpret=interpret,
+        compiler_params=_TPU_PARAMS,
+        interpret=kernels.interpret_mode(),
     )(x_signed_p, w_signed_p, noise)
 
 
@@ -142,7 +144,7 @@ def sc_mac_fused_prng(seed, x_signed_p, w_signed_p, *, nbit: int = 1024,
     """TPU-only variant: Gaussian noise generated ON-CHIP per output tile
     (``pltpu.prng_random_bits`` + Box-Muller), cutting HBM traffic from
     (MK + KN + 2MN) to (MK + KN + MN) floats. No CPU interpret path —
-    ``pltpu.prng_*`` has no interpreter implementation in this container —
+    ``pltpu.prng_*`` has no interpreter implementation on the CPU —
     so correctness is carried by the epilogue-math equivalence with
     ``sc_mac_fused`` (identical accumulators, tested) and the Box-Muller
     transform (unit-tested on CPU directly). seed: (1,) int32."""
@@ -159,30 +161,21 @@ def sc_mac_fused_prng(seed, x_signed_p, w_signed_p, *, nbit: int = 1024,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu_smem()),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[_vmem(bm, bn), _vmem(bm, bn), _vmem(bm, bn)],
-        compiler_params=_tpu_params(),
+        compiler_params=_TPU_PARAMS,
         interpret=False,
     )(seed, x_signed_p, w_signed_p)
 
 
-def pltpu_smem():
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.SMEM
-
-
 def _vmem(bm, bn):
-    from jax.experimental.pallas import tpu as pltpu
     return pltpu.VMEM((bm, bn), jnp.float32)
 
 
-def _tpu_params():
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
+_TPU_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))

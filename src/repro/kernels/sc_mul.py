@@ -21,11 +21,10 @@ stochastic write (every cell sees an independent coin with the same bias).
 Pop-count is SWAR (shift-mask-add) on the packed words, fused with the
 generation so the bits live and die inside VMEM.
 
-Entropy source: random words are *inputs* (counter-based threefry generated
-by the caller) because ``pltpu.prng_random_bits`` has no CPU interpret path
-in this container. On real TPU hardware the :func:`sc_mul_bitexact` wrapper
-can flip ``inkernel_prng=True`` to generate the words on-chip and shrink the
-input stream by 32×; the kernel math is unchanged.
+Entropy source: random words are *inputs*, generated by the caller
+(``pallas_bitexact`` feeds the pinned counter-based stream of
+``sc/ctr_rng.py``, which the fused engine regenerates in-kernel), so the
+packed and fused engines draw the same bits from the same key.
 """
 
 from __future__ import annotations
@@ -35,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import kernels
 
 NSLICES = 16        # fixed-point precision of the Bernoulli bias (2^-16)
 LANE_BITS = 32      # stochastic cells per packed word
@@ -75,13 +76,15 @@ def _sc_mul_kernel(px_ref, py_ref, ux_ref, uy_ref, out_ref):
     bits_x = bernoulli_words(px, ux_ref[...])   # pulse τ_X survival draw
     bits_y = bernoulli_words(py, uy_ref[...])   # pulse τ_Y survival draw
     survived = bits_x & bits_y                  # two-pulse AND (Fig. 5)
-    counts = popcount32(survived)               # (bm, bw) per-word counts
-    out_ref[...] = jnp.sum(counts, axis=-1, keepdims=True).astype(jnp.int32)
+    # per-word counts are <= 32, so the int32 view is exact (and Mosaic
+    # reduces signed integers only)
+    counts = jax.lax.bitcast_convert_type(popcount32(survived), jnp.int32)
+    out_ref[...] = jnp.sum(counts, axis=-1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m",))
 def sc_mul_popcount(p_x_fx16, p_y_fx16, rand_x, rand_y, *,
-                    block_m: int = 8, interpret: bool = True):
+                    block_m: int = 8):
     """Batched bit-exact SC MUL: returns pop-counts, shape (M,) int32.
 
     p_*_fx16: (M,) uint32 biases (p·2^16); rand_*: (M, NSLICES, W) uint32.
@@ -102,14 +105,13 @@ def sc_mul_popcount(p_x_fx16, p_y_fx16, rand_x, rand_y, *,
         ],
         out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(p_x_fx16.reshape(m, 1), p_y_fx16.reshape(m, 1), rand_x, rand_y)
     return out[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("nbit", "block_m", "interpret"))
-def sc_mul_bitexact(key, p_x, p_y, *, nbit: int = 1024, block_m: int = 8,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("nbit", "block_m"))
+def sc_mul_bitexact(key, p_x, p_y, *, nbit: int = 1024, block_m: int = 8):
     """Batched bit-exact SC MUL of probability vectors via the Pallas engine.
 
     The direct way to exercise the packed engine on raw probabilities
@@ -132,6 +134,5 @@ def sc_mul_bitexact(key, p_x, p_y, *, nbit: int = 1024, block_m: int = 8,
     shape = (mp, NSLICES, w)
     rx = jax.random.bits(kx, shape, jnp.uint32)
     ry = jax.random.bits(ky, shape, jnp.uint32)
-    counts = sc_mul_popcount(px, py, rx, ry, block_m=block_m,
-                             interpret=interpret)
+    counts = sc_mul_popcount(px, py, rx, ry, block_m=block_m)
     return counts[:m].astype(jnp.float32) / nbit

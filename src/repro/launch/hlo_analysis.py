@@ -45,9 +45,9 @@ _HEADER_RE = re.compile(
 
 # HBM-byte accounting follows a TPU fusion model: only ops that would
 # materialize a buffer on a well-fused TPU pipeline count; elementwise
-# chains are assumed fused into their producers/consumers. CPU HLO (this
-# container) barely fuses, so summing every op's operands would overstate
-# HBM traffic by an order of magnitude — see DESIGN.md §Roofline-method.
+# chains are assumed fused into their producers/consumers. CPU HLO barely
+# fuses, so summing every op's operands would overstate HBM traffic by an
+# order of magnitude.
 _BYTES_OPS = {
     "dot": "io",                     # operands + output (weights stream HBM)
     "convolution": "io",
@@ -479,11 +479,12 @@ def model_flops_estimate(cfg, shape) -> float:
 
 
 def _spec_leaves_with_paths(cfg):
+    import jax
+
     from repro.models import lm as lm_mod
     from repro.models.params import ParamSpec
     specs = lm_mod.lm_param_specs(cfg)
-    from repro.compat import tree_flatten_with_path
-    flat, _ = tree_flatten_with_path(
+    flat, _ = jax.tree.flatten_with_path(
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     return [([str(getattr(p, "key", "")) for p in path], s)
             for path, s in flat]

@@ -3,7 +3,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Per-cell HLO profile: where the bytes / FLOPs / collective traffic live.
 
-The §Perf hypothesis loop's "profiler" on a CPU-only container: lowers one
+A "profiler" that needs no accelerator: lowers one
 (arch x shape) cell on the production mesh and prints the per-device byte
 breakdown by opcode, the collective breakdown by (kind, operand size), and
 the while-loop trip counts the analyzer resolved.

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro import obs, serve
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm, params as params_lib
 from repro.serve import Request
@@ -77,6 +78,7 @@ def main(argv=None):
     cfg = cfg.replace(param_dtype=jnp.float32, act_dtype=jnp.float32)
     if cfg.frontend == "embeddings":
         raise SystemExit("serve demo uses token-frontend archs")
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(options.seed)
     params = params_lib.init_params(key, lm.lm_param_specs(cfg),

@@ -20,24 +20,6 @@ from repro.models import lm, params as params_lib
 from repro.sharding import rules as sharding_rules
 
 
-def abstract_mesh(axis_sizes, axis_names):
-    """Version-compat AbstractMesh constructor.
-
-    jax <= 0.4.x spells it ``AbstractMesh((("data", 16), ("model", 16)))``
-    (a tuple of (name, size) pairs); jax >= 0.5 spells it
-    ``AbstractMesh((16, 16), ("data", "model"))``. Tests and tooling build
-    production-scale sharding trees through this shim so either jax works.
-    """
-    import inspect
-
-    from jax.sharding import AbstractMesh
-
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
 def _sizes(mesh):
     # mesh.shape works for both concrete Mesh and AbstractMesh (tests build
     # the production sharding trees without 512 devices).

@@ -25,6 +25,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLMData, make_batch
 from repro.data.pipeline import make_embedding_batch
 from repro.ft import FaultInjector, Supervisor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, make_train_step, train_state_init
@@ -60,6 +61,7 @@ def main(argv=None):
     if args.sc_backend or args.sc_mode:
         cfg = cfg.replace(sc_backend=args.sc_backend or args.sc_mode)
 
+    enable_compile_cache()
     mesh = make_local_mesh()
     tcfg = TrainConfig(
         optimizer=AdamWConfig(lr=args.lr, total_steps=args.steps,

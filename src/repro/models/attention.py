@@ -258,15 +258,17 @@ def chunk_decode_attention(q, k_cache, v_cache, lengths):
 def paged_gather(pages, block_table):
     """Materialize each sequence's cache view from the block pool.
 
-    pages: (P, bs, kv, d) — the pool (P blocks of bs tokens, one layer);
-    block_table: (b, nb) int32 — block ids per sequence, position t of row
-    r lives in ``pages[block_table[r, t // bs], t % bs]``.  Returns the
-    gathered (b, nb·bs, kv, d) view — the contiguous-cache layout, which
-    is what proves paged == contiguous attention (same downstream math).
+    pages: (P, kv, bs, d) — the pool (P blocks of bs tokens, one layer;
+    each block holds one contiguous (bs, d) slab per kv head, the slice
+    the fused kernel DMAs per grid step); block_table: (b, nb) int32 —
+    block ids per sequence, position t of row r lives in
+    ``pages[block_table[r, t // bs], :, t % bs]``.  Returns the gathered
+    (b, nb·bs, kv, d) view — the contiguous-cache layout, which is what
+    proves paged == contiguous attention (same downstream math).
     """
-    g = jnp.take(pages, block_table, axis=0)  # (b, nb, bs, kv, d)
-    b, nb, bs = g.shape[:3]
-    return g.reshape(b, nb * bs, *g.shape[3:])
+    g = jnp.take(pages, block_table, axis=0)  # (b, nb, kv, bs, d)
+    b, nb, kv, bs, d = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, kv, d)
 
 
 def paged_scatter(pages, block_table, new, lengths, n_valid):
@@ -282,7 +284,7 @@ def paged_scatter(pages, block_table, new, lengths, n_valid):
     — ``kv_cache.PagedKVCache.make_writable``), so scatters never collide
     except harmlessly inside the null block.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     b, sc = new.shape[:2]
     nb = block_table.shape[1]
     i = jnp.arange(sc)[None, :]
@@ -292,7 +294,7 @@ def paged_scatter(pages, block_table, new, lengths, n_valid):
     page = jnp.where(valid, page, 0)
     off = jnp.where(valid, t % bs, 0)
     flat = new.reshape(b * sc, *new.shape[2:]).astype(pages.dtype)
-    return pages.at[page.reshape(-1), off.reshape(-1)].set(flat)
+    return pages.at[page.reshape(-1), :, off.reshape(-1)].set(flat)
 
 
 def paged_copy_blocks(pages, src, dst):
@@ -302,7 +304,7 @@ def paged_copy_blocks(pages, src, dst):
     (``kv_cache.PagedKVCache.make_writable``) replaces a shared or
     hash-registered block in a sequence's table, the new block must carry
     the old block's K/V before the next scatter overwrites its tail.
-    pages: the pool dict with ``{"k", "v"}`` (layers, P, bs, kv, d)
+    pages: the pool dict with ``{"k", "v"}`` (layers, P, kv, bs, d)
     arrays (extra non-paged leaves — e.g. a hybrid plan's ``"ssm"`` state
     rows, which are slot- not block-indexed — pass through untouched);
     src/dst: equal-length block-id vectors.  Pure indexed-copy — one

@@ -285,7 +285,8 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
     ``serve/kv_cache.py:CachePlan``).
 
     Attention families: one pool of ``num_blocks`` fixed-size token
-    blocks per layer, addressed through per-sequence block tables
+    blocks per layer, laid out (blocks, kv heads, block_size, head_dim)
+    and addressed through per-sequence block tables
     (``serve/kv_cache.py`` owns the allocator; block 0 is the reserved
     null block padding writes land in).
 
@@ -310,13 +311,13 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
             lambda v: jnp.broadcast_to(v[None], (n,) + v.shape).copy(), one)}
         if cfg.family == "hybrid":
             ninv = n_shared_invocations(cfg)
-            pages["k"] = jnp.zeros((ninv, num_blocks, block_size, kvh, hd),
+            pages["k"] = jnp.zeros((ninv, num_blocks, kvh, block_size, hd),
                                    dtype)
-            pages["v"] = jnp.zeros((ninv, num_blocks, block_size, kvh, hd),
+            pages["v"] = jnp.zeros((ninv, num_blocks, kvh, block_size, hd),
                                    dtype)
         return pages
-    return {"k": jnp.zeros((n, num_blocks, block_size, kvh, hd), dtype),
-            "v": jnp.zeros((n, num_blocks, block_size, kvh, hd), dtype)}
+    return {"k": jnp.zeros((n, num_blocks, kvh, block_size, hd), dtype),
+            "v": jnp.zeros((n, num_blocks, kvh, block_size, hd), dtype)}
 
 
 def _reset_fresh_state(cache, lengths):

@@ -42,18 +42,27 @@ DEFAULT_CACHE_PATH = os.path.join(os.path.dirname(__file__),
                                   "autotune_cache.json")
 _CACHE_ENV = "REPRO_SC_AUTOTUNE_CACHE"
 
-# per-tile uint32 working set cap (words): two Bernoulli word buffers of
-# bm*bk*bn*lane_words words each must stay VMEM-resident on a real TPU.
+# per-step uint32 working-set cap (words) of the SC paged-attention
+# kernel's (block_q, block_size, head_dim, lane_words) Bernoulli draws.
 _MAX_TILE_WORDS = 1 << 16
+
+# VMEM the fused matmul's pipelined blocks may take: double-buffered x^T,
+# w and output tiles plus the int32 accumulator, padded to the (8, 128)
+# tiling — half of the 16 MiB scoped VMEM a v5e kernel gets by default.
+_VMEM_BLOCK_BYTES = 8 << 20
+# largest contraction block: K longer than this steps over the grid
+_MAX_BLOCK_K = 1024
 
 
 @dataclasses.dataclass(frozen=True)
 class FusedTile:
-    """One fused-kernel tiling: matmul blocks + RNG words per inner pass.
+    """One fused-kernel tiling: matmul blocks + RNG words per inner step.
 
     ``lane_words`` packed 32-bit words (= 32·lane_words stochastic cells
-    per lane pass) are drawn per Horner-ladder sweep; smaller values
-    shrink the VMEM working set, larger values amortize sweep overhead.
+    per product) are drawn per unrolled step of the kernel's word loop;
+    larger values amortize loop overhead at the cost of a bigger kernel
+    body.  The kernel aligns the blocks to the TPU tiling
+    (``kernels/sc_fused.py:aligned_blocks``).
     """
 
     block_m: int = 8
@@ -172,23 +181,42 @@ def _pow2_cover(dim: int, cap: int) -> int:
     return p
 
 
-def heuristic_tile(m: int, k: int, n: int, nbit: int) -> FusedTile:
-    """Deterministic cache-miss fallback: modest power-of-two blocks.
+def _round_up(v: int, m: int) -> int:
+    return -(-v // m) * m
 
-    Small M/N tiles keep the cubic (bm, bk, bn, lane_words) Bernoulli
-    working set bounded; K gets the largest block the VMEM cap allows so
-    the integer accumulator loops as few grid steps as possible.
+
+def tile_vmem_bytes(tile: FusedTile) -> int:
+    """VMEM bytes a fused-matmul tile's blocks take (see
+    ``_VMEM_BLOCK_BYTES``)."""
+    x = _round_up(tile.block_k, 8) * _round_up(tile.block_m, 128)
+    w = _round_up(tile.block_k, 8) * _round_up(tile.block_n, 128)
+    out = _round_up(tile.block_m, 8) * _round_up(tile.block_n, 128)
+    return 4 * (2 * (x + w + out) + out)
+
+
+def _lane_block(n: int) -> int:
+    """Output-column block: all of a narrow N, else the lane multiple up
+    to 512 that pads N least (ties go to the wider block)."""
+    if n <= 128:
+        return n
+    return min((512, 384, 256, 128), key=lambda c: (_round_up(n, c), -c))
+
+
+def heuristic_tile(m: int, k: int, n: int, nbit: int) -> FusedTile:
+    """Deterministic cache-miss fallback, aligned to the TPU tiling.
+
+    Rows: all of them up to 128 (the kernel walks them one at a time).
+    Columns: lane-dense blocks that pad N least.  Contraction: as few
+    sublane-aligned blocks as ``_MAX_BLOCK_K`` allows, so the integer
+    accumulator loops few grid steps and K pads by under 8 per block.
+    One RNG word per unrolled inner step keeps the kernel body small.
     """
-    nwords = max(1, nbit // 32)
-    bm = _pow2_cover(m, 8)
-    bn = _pow2_cover(n, 8)
-    bk = _pow2_cover(k, 32)
-    lane = min(nwords, 16)
-    while bm * bn * bk * lane > _MAX_TILE_WORDS and lane > 1:
-        lane //= 2
-    while bm * bn * bk * lane > _MAX_TILE_WORDS and bk > 1:
-        bk //= 2
-    return FusedTile(block_m=bm, block_n=bn, block_k=bk, lane_words=lane)
+    del nbit
+    bm = min(m, 128)
+    bn = _lane_block(n)
+    nk = -(-k // _MAX_BLOCK_K)
+    bk = _round_up(-(-k // nk), 8)
+    return FusedTile(block_m=bm, block_n=bn, block_k=bk, lane_words=1)
 
 
 def get_tile(m: int, k: int, n: int, nbit: int, dtype: str = "float32",
@@ -223,20 +251,17 @@ def heuristic_attn_tile(rows: int, block_size: int, head_dim: int,
     """Deterministic cache-miss fallback for the paged-attention kernel.
 
     Deterministic QK^T (``nbit <= 0``) draws no stochastic words, so the
-    only knob is ``block_q``; the SC variant bounds its per-step
-    (block_q, block_size, head_dim, lane_words) Bernoulli working set by
-    the same VMEM cap as the matmul tiles.
+    only knob is ``block_q``.  The SC variant unrolls one RNG word per
+    step of its word loop (the smallest kernel body, quickest to
+    compile) and bounds its per-step (block_q, block_size, head_dim)
+    Bernoulli working set by the VMEM cap.
     """
     bq = _pow2_cover(rows, 8)
     if nbit <= 0:
         return AttnTile(block_q=bq, lane_words=1)
-    nwords = max(1, nbit // 32)
-    lane = min(nwords, 16)
-    while bq * block_size * head_dim * lane > _MAX_TILE_WORDS and lane > 1:
-        lane //= 2
-    while bq * block_size * head_dim * lane > _MAX_TILE_WORDS and bq > 1:
+    while bq * block_size * head_dim > _MAX_TILE_WORDS and bq > 1:
         bq //= 2
-    return AttnTile(block_q=bq, lane_words=lane)
+    return AttnTile(block_q=bq, lane_words=1)
 
 
 def get_attn_tile(rows: int, block_size: int, head_dim: int, nbit: int,
@@ -268,19 +293,19 @@ def get_attn_tile(rows: int, block_size: int, head_dim: int, nbit: int,
 def candidate_tiles(m: int, k: int, n: int, nbit: int) -> list:
     """The tuner's search space for one call shape (heuristic included).
 
-    Deliberately small: each candidate pays a fresh kernel compile, and
-    tiny ``lane_words`` values are excluded outright — the Horner sweep
-    unrolls ``nwords / lane_words`` chunks, so small lanes inflate both
-    trace size (compile time) and per-step overhead.
+    Deliberately small: each candidate pays a fresh kernel compile.
+    Column blocks are lane multiples (or all of a narrow N), contraction
+    blocks sublane multiples, and every candidate fits the VMEM budget.
     """
     nwords = max(1, nbit // 32)
-    cands = []
-    for bm in {_pow2_cover(m, c) for c in (4, 8, 16)}:
-        for bn in {_pow2_cover(n, c) for c in (4, 8, 16)}:
-            for bk in {_pow2_cover(k, c) for c in (16, 32, 64)}:
-                for lane in {min(nwords, c) for c in (16, 32)}:
-                    if bm * bn * bk * lane <= _MAX_TILE_WORDS:
-                        cands.append(FusedTile(bm, bn, bk, lane))
+    bm = min(m, 128)
+    bns = {n} if n <= 128 else {c for c in (128, 256, 512)
+                                if c <= _round_up(n, 128)}
+    bks = {min(_round_up(k, 8), c) for c in (256, 512, 1024)}
+    cands = [FusedTile(bm, bn, bk, lane)
+             for bn in bns for bk in bks
+             for lane in {min(nwords, c) for c in (1, 2, 4)}]
+    cands = [t for t in cands if tile_vmem_bytes(t) <= _VMEM_BLOCK_BYTES]
     cands.append(heuristic_tile(m, k, n, nbit))
     return sorted(set(cands), key=lambda t: dataclasses.astuple(t))
 
@@ -293,22 +318,20 @@ def measure_tile(m: int, k: int, n: int, nbit: int, tile: FusedTile, *,
     import jax.numpy as jnp
 
     from repro.kernels import sc_fused
-    from repro.sc import ctr_rng, encoding
+    from repro.sc import ctr_rng
 
     key = jax.random.PRNGKey(seed)
     kx, kw, kk = jax.random.split(key, 3)
     x = jax.random.uniform(kx, (m, k), jnp.float32, -1.0, 1.0)
     w = jax.random.uniform(kw, (k, n), jnp.float32, -1.0, 1.0)
     kx2, ky2 = jax.random.split(kk)
-    xp = encoding.pad_to(encoding.pad_to(x, tile.block_m, 0), tile.block_k, 1)
-    wp = encoding.pad_to(encoding.pad_to(w, tile.block_k, 0), tile.block_n, 1)
     keys = jnp.broadcast_to(
         jnp.concatenate([ctr_rng.raw_key(kx2), ctr_rng.raw_key(ky2)])[None],
-        (xp.shape[0], 4))
+        (m, 4))
 
     def run():
         return sc_fused.sc_fused_popcount(
-            keys, xp, wp, k_orig=k, n_orig=n, nbit=nbit,
+            keys, x, w, k_orig=k, n_orig=n, nbit=nbit,
             levels=1 << operand_bits, quantize=True,
             **tile.kwargs()).block_until_ready()
 
@@ -377,9 +400,9 @@ def measure_attn_tile(rows: int, block_size: int, head_dim: int, nbit: int,
     q = jax.random.uniform(kq, (1, rows, 1, head_dim), jnp.float32,
                            -1.0, 1.0)
     k_pages = jax.random.uniform(
-        kk, (num_pages, block_size, 1, head_dim), jnp.float32, -1.0, 1.0)
+        kk, (num_pages, 1, block_size, head_dim), jnp.float32, -1.0, 1.0)
     v_pages = jax.random.uniform(
-        kv, (num_pages, block_size, 1, head_dim), jnp.float32, -1.0, 1.0)
+        kv, (num_pages, 1, block_size, head_dim), jnp.float32, -1.0, 1.0)
     table = jnp.arange(num_pages, dtype=jnp.int32)[None]
     lengths = jnp.array([num_pages * block_size - rows], jnp.int32)
     keys = jnp.broadcast_to(ctr_rng.raw_key(key)[None, None],

@@ -96,7 +96,7 @@ def pallas_moment(key, x, w, cfg: ScConfig):
     noise = jax.random.normal(key, (xs.shape[0], ws.shape[1]), jnp.float32)
     out = sc_mac_kernel.sc_mac_fused(
         xs, ws, noise, nbit=cfg.nbit, block_m=cfg.block_m,
-        block_n=cfg.block_n, block_k=cfg.block_k, interpret=cfg.interpret)
+        block_n=cfg.block_n, block_k=cfg.block_k)
     return out[: x.shape[0], : w.shape[1]] * (scx * scw)
 
 
@@ -127,7 +127,7 @@ def pallas_bitexact(key, x, w, cfg: ScConfig):
     rx = ctr_rng.operand_stream(ctr_rng.raw_key(kx), pxf.shape[0], nwords)
     ry = ctr_rng.operand_stream(ctr_rng.raw_key(ky), pxf.shape[0], nwords)
     counts = sc_mul_kernel.sc_mul_popcount(
-        pxf, pwf, rx, ry, block_m=_MUL_BLOCK_M, interpret=cfg.interpret)
+        pxf, pwf, rx, ry, block_m=_MUL_BLOCK_M)
     counts3 = counts[: m * k * n].reshape(m, k, n)
     # exact signed integer reduction over K: associative, so it matches
     # the fused kernel's per-tile accumulation bit-for-bit
@@ -153,16 +153,11 @@ def _fused_engine(keys4, x, w, cfg: ScConfig, scx, scw, *, row_keys):
     m, k = x.shape
     n = w.shape[1]
     tile = autotune.get_tile(m, k, n, cfg.nbit)
-    keys4 = encoding.pad_to(keys4, tile.block_m, 0)
-    spx = encoding.pad_to(
-        encoding.pad_to(x / scx, tile.block_m, 0), tile.block_k, 1)
-    spw = encoding.pad_to(
-        encoding.pad_to(w / scw, tile.block_k, 0), tile.block_n, 1)
     total = sc_fused_kernel.sc_fused_popcount(
-        keys4, spx, spw, k_orig=k, n_orig=n, nbit=cfg.nbit,
+        keys4, x / scx, w / scw, k_orig=k, n_orig=n, nbit=cfg.nbit,
         levels=1 << cfg.operand_bits, quantize=cfg.quantize,
-        row_keys=row_keys, interpret=cfg.interpret, **tile.kwargs())
-    return total[:m, :n].astype(jnp.float32) / cfg.nbit * (scx * scw)
+        row_keys=row_keys, **tile.kwargs())
+    return total.astype(jnp.float32) / cfg.nbit * (scx * scw)
 
 
 @register_backend("pallas_fused")

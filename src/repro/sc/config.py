@@ -38,8 +38,10 @@ class ScConfig:
             backend-numerics studies on un-quantized operands).
         block_m / block_n / block_k: Pallas moment-kernel tile shape
             (clamped per-call to the operand shape).
-        interpret: run Pallas kernels in interpreter mode (CPU-safe; this
-            container).  Real TPUs flip it off to compile through Mosaic.
+
+    Whether a Pallas kernel is interpreted or compiled is not
+    configuration: it follows the platform
+    (:func:`repro.kernels.interpret_mode`).
     """
 
     backend: str = "exact"      # name in the repro.sc registry
@@ -50,9 +52,6 @@ class ScConfig:
     block_m: int = 128
     block_n: int = 128
     block_k: int = 512
-    # interpret=True runs the kernels on CPU (this container); real TPUs
-    # flip it off to compile through Mosaic.
-    interpret: bool = True
     # Device-realism profile (core/physics.py:DeviceProfile): frozen
     # per-cell variation + bit-error rates.  None or an ideal profile is
     # bit-identical to the paper's idealized math on every backend; a

@@ -19,6 +19,7 @@ each carry a copy of this logic; this module is the single home now.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -70,10 +71,12 @@ def to_fx16(p):
     has no 16-bit word (w = 2^16 needs a 17th bit) and clamps to 65535;
     :func:`encode`'s clamped grid keeps quantized probabilities at
     (2^n - 1)/2^n or below, so the packed Pallas path never hits the clamp
-    and max-magnitude operands are no longer biased downward.
+    and max-magnitude operands are no longer biased downward.  The word
+    converts through int32 (same value, < 2^16), a cast the fused
+    kernel's TPU lowering supports where float -> uint32 is not.
     """
-    return jnp.clip(jnp.round(p * FX16_ONE), 0, FX16_ONE - 1).astype(
-        jnp.uint32)
+    w = jnp.clip(jnp.round(p * FX16_ONE), 0, FX16_ONE - 1).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type(w, jnp.uint32)
 
 
 def from_fx16(w):

@@ -120,8 +120,6 @@ def sc_dot_sharded(key, x, w, cfg: ScConfig = ScConfig(), *, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map_compat
-
     lead = x.shape[:-1]
     k_dim = x.shape[-1]
     m = math.prod(lead) if lead else 1
@@ -142,11 +140,11 @@ def sc_dot_sharded(key, x, w, cfg: ScConfig = ScConfig(), *, mesh,
             y = jax.lax.psum(y, r.contract)
         return y
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(batch_spec, contract_spec), P(contract_spec, None)),
         out_specs=P(batch_spec, None),
-        check_rep=False)
+        check_vma=False)
     with shard_scope(n_shards):
         y = mapped(key, x2, w)
     return y.reshape(*lead, w.shape[-1])

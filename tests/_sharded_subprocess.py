@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import arch, sc
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 8, jax.devices()
 
@@ -20,9 +21,9 @@ x = jax.random.normal(jax.random.PRNGKey(1), (8, 32))
 w = jax.random.normal(jax.random.PRNGKey(2), (32, 6))
 exact = np.asarray(x @ w)
 
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
-mesh18 = jax.make_mesh((1, 8), ("data", "model"))
-mesh81 = jax.make_mesh((8, 1), ("data", "model"))
+mesh24 = make_mesh((2, 4), ("data", "model"))
+mesh18 = make_mesh((1, 8), ("data", "model"))
+mesh81 = make_mesh((8, 1), ("data", "model"))
 
 # --- identical keys => identical bits when no axis actually splits -------
 # On a 1x8 mesh with rules naming only the (size-1) data axis, resolve_rules

@@ -11,6 +11,7 @@ from repro import checkpoint
 from repro.configs import get_smoke_config
 from repro.data import SyntheticLMData, make_batch
 from repro.ft import FaultInjector, StragglerMonitor, Supervisor, WorkerFailure
+from repro.launch.mesh import make_mesh
 from repro.train import TrainConfig, make_train_step
 from repro.train.step import train_state_init
 
@@ -61,7 +62,7 @@ def test_restore_missing_raises(tmp_path):
 def test_restore_resharded_on_local_mesh(tmp_path, key):
     """Elastic restore: device_put with shardings from the current mesh."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"w": jax.random.normal(key, (8, 8))}
     checkpoint.save(str(tmp_path), 2, tree)
     sh = {"w": NamedSharding(mesh, P("data", "model"))}

@@ -12,6 +12,7 @@ from repro.distributed import compression
 from repro.models.params import ParamSpec, partition_specs
 from repro.sharding import act_spec
 from repro.sharding.rules import logical_rules
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # Data pipeline
@@ -75,7 +76,7 @@ def test_zipf_structure_has_repeats(step):
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_partition_specs_divisible_dims_shard():
@@ -109,7 +110,7 @@ def test_act_spec_single_pod_mesh():
 
 
 def test_logical_rules_pod_axis():
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     rules = logical_rules(mesh, "act")
     assert rules["batch"] == ("pod", "data")
     assert rules["__sizes__"] == {"pod": 1, "data": 1, "model": 1}

@@ -1,5 +1,6 @@
-"""Pallas kernel validation (interpret=True on CPU): shape/dtype sweeps
-asserted against the pure-jnp oracles in kernels/ref.py."""
+"""Pallas kernel validation (interpreted on the CPU; the mode follows the
+platform): shape/dtype sweeps asserted against the pure-jnp oracles in
+kernels/ref.py."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ def test_sc_mul_kernel_matches_ref_exactly(key, m, w, block_m):
     py = to_fx16(jax.random.uniform(jax.random.fold_in(kp, 1), (m,)))
     rx = jax.random.bits(kx, (m, NSLICES, w), jnp.uint32)
     ry = jax.random.bits(ky, (m, NSLICES, w), jnp.uint32)
-    out_k = sc_mul_popcount(px, py, rx, ry, block_m=block_m, interpret=True)
+    out_k = sc_mul_popcount(px, py, rx, ry, block_m=block_m)
     out_r = ref.sc_mul_popcount_ref(px, py, rx, ry)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
@@ -40,7 +41,7 @@ def test_sc_mul_bias_edges(key):
                          jnp.uint32)
     zeros = jnp.zeros((m,), jnp.uint32)
     out = sc_mul_popcount(zeros, to_fx16(jnp.ones(m) * 0.5), rx, ry,
-                          block_m=8, interpret=True)
+                          block_m=8)
     assert int(jnp.sum(out)) == 0
 
 
@@ -81,7 +82,7 @@ def test_sc_mac_fused_matches_ref(key, m, k, n, bm, bn, bk):
     w = jax.random.uniform(kw, (k, n), jnp.float32, -1.0, 1.0)
     noise = jax.random.normal(kn, (m, n), jnp.float32)
     out = sc_mac_fused(x, w, noise, nbit=1024, block_m=bm, block_n=bn,
-                       block_k=bk, interpret=True)
+                       block_k=bk)
     expect = ref.sc_mac_ref(x, w, noise, nbit=1024)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
@@ -96,8 +97,7 @@ def test_sc_mac_fused_dtype_sweep(key, dtype):
     noise = jax.random.normal(jax.random.fold_in(key, 2), (32, 32),
                               jnp.float32)
     out = sc_mac_fused(x.astype(jnp.float32), w.astype(jnp.float32), noise,
-                       nbit=512, block_m=32, block_n=32, block_k=64,
-                       interpret=True)
+                       nbit=512, block_m=32, block_n=32, block_k=64)
     expect = ref.sc_mac_ref(x.astype(jnp.float32), w.astype(jnp.float32),
                             noise, nbit=512)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),

@@ -28,8 +28,8 @@ def _rand_paged(rng, *, b, sc, h, kvh, hd, bs, nb):
     """Random pool + shuffled block tables (page ids deliberately not
     contiguous, so in-kernel gather is actually exercised)."""
     P_ = b * nb + 2
-    k_pages = jnp.asarray(rng.normal(size=(P_, bs, kvh, hd)), jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(P_, bs, kvh, hd)), jnp.float32)
+    k_pages = jnp.asarray(rng.normal(size=(P_, kvh, bs, hd)), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(P_, kvh, bs, hd)), jnp.float32)
     bt = jnp.asarray(rng.permutation(P_)[:b * nb].reshape(b, nb), jnp.int32)
     q = jnp.asarray(rng.normal(size=(b, sc, h, hd)), jnp.float32)
     return q, k_pages, v_pages, bt
@@ -177,14 +177,11 @@ def test_sc_kernel_matches_host_twin_bitwise():
     parts = []
     for j in range(nb):
         page = int(bt[r, j])
-        t_abs = (jnp.uint32(j * bs)
-                 + jax.lax.broadcasted_iota(jnp.uint32, (1, bs, hd), 1))
-        d_idx = jax.lax.broadcasted_iota(jnp.uint32, (1, bs, hd), 2)
-        c0 = ((t_abs * jnp.uint32(h) + jnp.uint32(head)) * jnp.uint32(hd)
-              + d_idx)
+        t_abs = j * bs + jnp.arange(bs, dtype=jnp.int32)[None]
         parts.append(np.asarray(pa._sc_logits(
-            q[r, i0, head][None], kp[page, :, kh, :], keys4[r, i0][None],
-            c0, nbit=128, levels=1 << 10, quantize=True, lane=4)[0]))
+            q[r, i0, head][None], kp[page, kh], keys4[r, i0][None],
+            t_abs, jnp.full((1, 1), head, jnp.int32), n_heads=h,
+            nbit=128, levels=1 << 10, quantize=True, lane=4)[0]))
     assert np.array_equal(np.concatenate(parts), np.asarray(host))
 
 
@@ -252,8 +249,8 @@ def test_sc_block_size_invariance():
     outs = []
     for bs in (4, 8):
         nb = T // bs
-        kp = kc.reshape(nb, bs, kvh, hd)
-        vp = vc.reshape(nb, bs, kvh, hd)
+        kp = kc.reshape(nb, bs, kvh, hd).transpose(0, 2, 1, 3)
+        vp = vc.reshape(nb, bs, kvh, hd).transpose(0, 2, 1, 3)
         # identity table but through a shuffled pool
         perm = np.asarray([2, 0, 3, 1][:nb])
         inv = np.argsort(perm)

@@ -21,6 +21,7 @@ from repro import sc
 from repro.configs import get_smoke_config
 from repro.models import layers
 from repro.sc import autotune
+from repro.launch.mesh import make_mesh
 
 _NBIT = 64      # 2 packed words per product: fast but fully exercised
 
@@ -203,8 +204,7 @@ def test_heuristic_fallback_on_cache_miss():
     # deterministic: same signature, same tile
     assert tile == autotune.get_tile(13, 40, 7, 1024, cache={})
     # blocks stay VMEM-bounded
-    assert (tile.block_m * tile.block_n * tile.block_k * tile.lane_words
-            <= autotune._MAX_TILE_WORDS)
+    assert autotune.tile_vmem_bytes(tile) <= autotune._VMEM_BLOCK_BYTES
 
 
 def test_cache_hit_returns_stored_tile(tmp_path):
@@ -289,8 +289,8 @@ def test_shipped_cache_loads_and_is_current_version():
             block_m=int(entry["block_m"]), block_n=int(entry["block_n"]),
             block_k=int(entry["block_k"]),
             lane_words=int(entry["lane_words"]))
-        assert (tile.block_m * tile.block_n * tile.block_k
-                * tile.lane_words <= autotune._MAX_TILE_WORDS), key_
+        assert autotune.tile_vmem_bytes(tile) <= \
+            autotune._VMEM_BLOCK_BYTES, key_
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +302,7 @@ def test_fused_sharded_trivial_mesh_bit_equal(key):
     """On a 1-device mesh every axis drops and sc_dot_sharded must equal
     sc_dot exactly (same key, same bits) — the multi-device equivalence
     runs in tests/_sharded_subprocess.py."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x, w = _xw(key, 8, 32, 8)
     cfg = sc.ScConfig(backend="pallas_fused", nbit=_NBIT)
     y_ref = sc.sc_dot(key, x, w, cfg)

@@ -14,6 +14,7 @@ import pytest
 from repro import arch, sc
 from repro.arch.accounting import merge_concurrent_reports, merge_reports
 from repro.sharding import sc_shard_rules
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(0)
 X = jax.random.normal(jax.random.PRNGKey(1), (4, 8))
@@ -21,7 +22,7 @@ W = jax.random.normal(jax.random.PRNGKey(2), (8, 5))
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

@@ -2,14 +2,13 @@
 validates the exact layouts the dry-run compiles with, without needing 512
 devices."""
 
-import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
 from repro.launch import specs as S
 
-POD = S.abstract_mesh((16, 16), ("data", "model"))
-MULTI = S.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def spec_of(sharding):

@@ -13,6 +13,7 @@ from repro.ft import Supervisor
 from repro.serve import Request, ServeConfig, ServingEngine
 from repro.train import TrainConfig, make_train_step
 from repro.train.step import train_state_init
+from repro.launch.mesh import make_mesh
 
 F32 = dict(param_dtype=jnp.float32, act_dtype=jnp.float32)
 
@@ -72,7 +73,7 @@ def test_shard_map_compression_on_pod_mesh(key):
     """compressed_grads wires shard_map over a pod axis (size 1 on CPU —
     semantics identical, collectives degenerate) and returns grads close to
     the uncompressed path (int8 quantization error only)."""
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     w0 = jax.random.normal(key, (8, 8))
 
     def grad_fn(params, batch):
