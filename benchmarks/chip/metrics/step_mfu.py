@@ -1,0 +1,15 @@
+"""Whole step: model FLOPs of the window's useful work (``counts.
+step_flops``: fed positions, valid attention context, the output
+projection of rows that produced logits) over the window at the chip's
+bf16 peak, %."""
+
+from benchmarks.chip import counts
+
+
+def read(ctx):
+    red = ctx.reduction
+    if red is None or not red.window_ns:
+        return None
+    c = ctx.cell.config
+    flops = sum(counts.step_flops(c, t.rows) for t in ctx.window.ticks)
+    return 100.0 * flops / (red.window_ns / 1e9 * ctx.peaks["bf16_flops"])
