@@ -297,9 +297,10 @@ def _rows_unlayout(out, *, sc: int, h: int):
     return out.reshape(b, sc, h, hd)
 
 
-def _launch(kernel, *, q_rows, block_table, lengths, extra, k_pages,
+def _launch(kernel, *, name, q_rows, block_table, lengths, extra, k_pages,
             v_pages, block_q):
-    """One fused-attention ``pallas_call`` over grid (b, kv, q tiles, nb).
+    """One fused-attention ``pallas_call`` over grid (b, kv, q tiles, nb),
+    named ``name`` in the compiled program and the profiler's trace.
 
     q_rows: (b, kvh, rows_p, hd) kernel rows; extra: per-row operands of
     shape (b, rows_p, w) blocked alongside the query tile.  The block
@@ -342,6 +343,7 @@ def _launch(kernel, *, q_rows, block_table, lengths, extra, k_pages,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=kernels.interpret_mode(),
+        name=name,
     )(block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
       q_rows, *extra, k_pages, v_pages)
 
@@ -384,9 +386,9 @@ def paged_attention_fused(
     kernel = functools.partial(
         _paged_attn_kernel, sc=sc, block_size=bs, nb=nb, block_q=block_q
     )
-    out = _launch(kernel, q_rows=qr, block_table=block_table,
-                  lengths=lengths, extra=(), k_pages=k_pages,
-                  v_pages=v_pages, block_q=block_q)
+    out = _launch(kernel, name="paged_attention", q_rows=qr,
+                  block_table=block_table, lengths=lengths, extra=(),
+                  k_pages=k_pages, v_pages=v_pages, block_q=block_q)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 
@@ -449,9 +451,9 @@ def paged_attention_fused_sc(
         quantize=quantize,
         lane_words=lane_words,
     )
-    out = _launch(kernel, q_rows=qr, block_table=block_table,
-                  lengths=lengths, extra=(rowk,), k_pages=k_pages,
-                  v_pages=v_pages, block_q=block_q)
+    out = _launch(kernel, name="paged_attention_sc", q_rows=qr,
+                  block_table=block_table, lengths=lengths, extra=(rowk,),
+                  k_pages=k_pages, v_pages=v_pages, block_q=block_q)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 

@@ -268,5 +268,6 @@ def sc_fused_popcount(keys, x_signed_p, w_signed_p, *, k_orig: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=kernels.interpret_mode(),
+        name="sc_fused_popcount",
     )(key_words, xt, wp)
     return out[:m, :n]

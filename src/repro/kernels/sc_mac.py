@@ -132,6 +132,7 @@ def sc_mac_fused(x_signed_p, w_signed_p, noise, *, nbit: int = 1024,
         scratch_shapes=[_vmem(bm, bn), _vmem(bm, bn), _vmem(bm, bn)],
         compiler_params=_TPU_PARAMS,
         interpret=kernels.interpret_mode(),
+        name="sc_mac",
     )(x_signed_p, w_signed_p, noise)
 
 
@@ -170,6 +171,7 @@ def sc_mac_fused_prng(seed, x_signed_p, w_signed_p, *, nbit: int = 1024,
         scratch_shapes=[_vmem(bm, bn), _vmem(bm, bn), _vmem(bm, bn)],
         compiler_params=_TPU_PARAMS,
         interpret=False,
+        name="sc_mac",
     )(seed, x_signed_p, w_signed_p)
 
 

@@ -106,6 +106,7 @@ def sc_mul_popcount(p_x_fx16, p_y_fx16, rand_x, rand_y, *,
         out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
         interpret=kernels.interpret_mode(),
+        name="sc_mul",
     )(p_x_fx16.reshape(m, 1), p_y_fx16.reshape(m, 1), rand_x, rand_y)
     return out[:, 0]
 

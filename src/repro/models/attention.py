@@ -58,32 +58,33 @@ def attn_specs(cfg):
 
 
 def _project_qkv(x, p, cfg, positions, key=None):
-    b, s, _ = x.shape
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    if key is None:
-        keys = [None] * 3
-    elif key.ndim > 1:
-        # Per-token key arrays (paged/chunked decode): one fold per
-        # projection instead of a split, so each token's draw stays a
-        # function of its own (request, position) key alone.
-        keys = [layers.fold_keys(key, 23 + j) for j in range(3)]
-    else:
-        keys = list(jax.random.split(key, 3))
-    q = layers.dense(x, p["wq"], cfg, keys[0], p.get("bq")).reshape(
-        b, s, h, hd
-    )
-    k = layers.dense(x, p["wk"], cfg, keys[1], p.get("bk")).reshape(
-        b, s, kv, hd
-    )
-    v = layers.dense(x, p["wv"], cfg, keys[2], p.get("bv")).reshape(
-        b, s, kv, hd
-    )
-    if cfg.qk_norm:
-        q = layers.rms_norm(q, p["q_norm"])
-        k = layers.rms_norm(k, p["k_norm"])
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    with jax.named_scope("qkv"):
+        b, s, _ = x.shape
+        h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+        if key is None:
+            keys = [None] * 3
+        elif key.ndim > 1:
+            # Per-token key arrays (paged/chunked decode): one fold per
+            # projection instead of a split, so each token's draw stays a
+            # function of its own (request, position) key alone.
+            keys = [layers.fold_keys(key, 23 + j) for j in range(3)]
+        else:
+            keys = list(jax.random.split(key, 3))
+        q = layers.dense(x, p["wq"], cfg, keys[0], p.get("bq")).reshape(
+            b, s, h, hd
+        )
+        k = layers.dense(x, p["wk"], cfg, keys[1], p.get("bk")).reshape(
+            b, s, kv, hd
+        )
+        v = layers.dense(x, p["wv"], cfg, keys[2], p.get("bv")).reshape(
+            b, s, kv, hd
+        )
+        if cfg.qk_norm:
+            q = layers.rms_norm(q, p["q_norm"])
+            k = layers.rms_norm(k, p["k_norm"])
+        q = layers.apply_rope(q, positions, cfg.rope_theta)
+        k = layers.apply_rope(k, positions, cfg.rope_theta)
+        return q, k, v
 
 
 def _grouped(q, kv_heads):
@@ -344,6 +345,20 @@ def paged_attention_block(
     (out, new_k_pages, new_v_pages).
     """
     q, k, v = _project_qkv(x, p, cfg, positions, key)
+    with jax.named_scope("attn"):
+        out, k_pages, v_pages = _paged_lookup(
+            q, k, v, cfg, key, k_pages, v_pages, block_table, lengths,
+            n_valid)
+    b, s, _, _ = out.shape
+    okey = layers.fold_keys(key, 7)
+    with jax.named_scope("attn_out"):
+        out = layers.dense(out.reshape(b, s, -1), p["wo"], cfg, okey)
+    return out, k_pages, v_pages
+
+
+def _paged_lookup(q, k, v, cfg, key, k_pages, v_pages, block_table, lengths,
+                  n_valid):
+    """Scatter the chunk's K/V into the pool, then attend over it."""
     k_pages = paged_scatter(k_pages, block_table, k, lengths, n_valid)
     v_pages = paged_scatter(v_pages, block_table, v, lengths, n_valid)
     mode = getattr(cfg, "paged_attn", "unfused")
@@ -376,13 +391,7 @@ def paged_attention_block(
             f"unknown cfg.paged_attn={mode!r} "
             "(expected 'unfused', 'fused', or 'fused_sc')"
         )
-    b, s, _, _ = out.shape
-    okey = layers.fold_keys(key, 7)
-    return (
-        layers.dense(out.reshape(b, s, -1), p["wo"], cfg, okey),
-        k_pages,
-        v_pages,
-    )
+    return out, k_pages, v_pages
 
 
 def attention_block(
@@ -420,6 +429,25 @@ def attention_block(
         q = cst(q, "batch", "resid_seq", None, None)
         k = cst(k, "batch", "seq", None, None)
         v = cst(v, "batch", "seq", None, None)
+    with jax.named_scope("attn"):
+        out, new_cache = _contiguous_lookup(q, k, v, cfg, cache,
+                                            cache_length, positions,
+                                            heads_tp)
+    if heads_tp or cache is not None:
+        out = cst(out, "batch", "seq", "heads", None)
+    else:
+        out = cst(out, "batch", "resid_seq", None, None)
+    b, s, _, _ = out.shape
+    okey = None if key is None else jax.random.fold_in(key, 7)
+    with jax.named_scope("attn_out"):
+        out = layers.dense(out.reshape(b, s, -1), p["wo"], cfg, okey)
+    return out, new_cache
+
+
+def _contiguous_lookup(q, k, v, cfg, cache, cache_length, positions,
+                       heads_tp):
+    """Attention over the ring-buffer cache (decode) or the sequence
+    itself (training/prefill).  Returns (out, new_cache)."""
     if cache is not None:
         kc, vc = cache
         pos = positions[:, 0]  # (b,) write index
@@ -449,10 +477,4 @@ def attention_block(
                 q_chunk=None if heads_tp else q.shape[1],
             )
         new_cache = (k, v)
-    if heads_tp or cache is not None:
-        out = cst(out, "batch", "seq", "heads", None)
-    else:
-        out = cst(out, "batch", "resid_seq", None, None)
-    b, s, _, _ = out.shape
-    okey = None if key is None else jax.random.fold_in(key, 7)
-    return layers.dense(out.reshape(b, s, -1), p["wo"], cfg, okey), new_cache
+    return out, new_cache

@@ -171,7 +171,8 @@ def mlp_specs(cfg):
 
 def mlp(x, p, cfg, key=None, constrain=None):
     cst = constrain or (lambda v, *a: v)
-    h = dense(x, p["wi"], cfg, site_key(key, "mlp_wi"), site="mlp_wi")
+    with jax.named_scope("mlp_in"):
+        h = dense(x, p["wi"], cfg, site_key(key, "mlp_wi"), site="mlp_wi")
     # TP over the hidden dim, full sequence inside the block (Megatron
     # pattern): without this pin Shardy reshards the multi-GB hidden between
     # seq-sharded and mlp-sharded layouts per invocation (observed 7.5 GB
@@ -183,7 +184,9 @@ def mlp(x, p, cfg, key=None, constrain=None):
     else:
         act = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
     act = cst(act, "batch", "seq", "mlp")
-    return dense(act, p["wo"], cfg, site_key(key, "mlp_wo"), site="mlp_wo")
+    with jax.named_scope("mlp_out"):
+        return dense(act, p["wo"], cfg, site_key(key, "mlp_wo"),
+                     site="mlp_wo")
 
 
 # ----------------------------- RoPE -----------------------------------------

@@ -90,11 +90,12 @@ def _logits(x, params, cfg, key=None):
     root — raw (2,) or per-row (..., 2) matching ``x``'s leading dims —
     folded here with the unembed site salt."""
     key = layers.site_key(key, "unembed")
-    if cfg.tie_embeddings:
-        return layers.unembed(x, params["embed"], cfg, key).astype(
-            jnp.float32)
-    return layers.dense(x, params["unembed"], cfg, key,
-                        site="unembed").astype(jnp.float32)
+    with jax.named_scope("unembed"):
+        if cfg.tie_embeddings:
+            return layers.unembed(x, params["embed"], cfg, key).astype(
+                jnp.float32)
+        return layers.dense(x, params["unembed"], cfg, key,
+                            site="unembed").astype(jnp.float32)
 
 
 def _group(tree, ninv: int, per: int):

@@ -14,6 +14,17 @@ durations are exact and ordering holds within one process; spans carry
 the recording thread id as ``tid`` so multi-threaded drivers stay
 readable in the Chrome view.
 
+An enabled tracer also writes every span into the JAX profiler's trace:
+``span()`` opens a ``jax.profiler.TraceAnnotation`` of the same name,
+with the attributes given at open (and any passed to
+:meth:`Span.annotate` before it closes) as the event's stats, on the
+profiler's clock beside the device's operations.  Attributes set later
+through :meth:`Tracer.attr`, and instant events, stay in memory only.
+Outside a profiler session an annotation records nothing.  While an
+enabled tracer is installed (:func:`install_tracer`), each Python
+garbage collection records a ``python.gc`` span (``generation``; and
+``collected``, in memory only).
+
 The module-global tracer slot (:func:`install_tracer` /
 :func:`current_tracer`) mirrors ``arch.trace``'s listener pattern: code
 that cannot be handed a tracer (backend dispatch running under a jax
@@ -26,9 +37,17 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import json
 import threading
 import time
+
+
+def _profiler():
+    """``jax.profiler``, imported on first use, so that importing
+    ``repro.obs`` imports nothing from JAX."""
+    from jax import profiler
+    return profiler
 
 
 @dataclasses.dataclass
@@ -42,6 +61,16 @@ class Span:
     span_id: int
     parent_id: int | None
     tid: int
+    # the open profiler annotation (None once closed, and for events)
+    annotation: object = dataclasses.field(default=None, repr=False,
+                                           compare=False)
+
+    def annotate(self, **attrs) -> None:
+        """Add attributes to this open span and to its profiler event's
+        stats (for values known only after the span opened)."""
+        self.attrs.update(attrs)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**attrs)
 
     def as_dict(self) -> dict:
         return {"name": self.name, "t0_ns": self.t0_ns,
@@ -61,9 +90,12 @@ class Tracer:
         self.enabled = enabled
         self.spans: list[Span] = []
         self._clock = clock
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection inside a locked section
+        # records its python.gc span through the same lock
+        self._lock = threading.RLock()
         self._local = threading.local()
         self._next_id = 1
+        self._gc_open = None            # the open python.gc span's context
 
     # ------------------------------------------------------------------
     def _stack(self) -> list:
@@ -85,23 +117,28 @@ class Tracer:
     # ------------------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Timed span around a block; yields the open Span (attrs are
-        mutable until exit).  Nesting sets ``parent_id``."""
+        """Timed span around a block, mirrored into the profiler's trace;
+        yields the open Span (attrs are mutable until exit).  Nesting
+        sets ``parent_id``."""
         if not self.enabled:
             yield _NULL_SPAN
             return
         stack = self._stack()
         parent = stack[-1].span_id if stack else None
-        s = Span(name=name, t0_ns=self._clock(), dur_ns=0, attrs=dict(attrs),
-                 span_id=self._alloc(), parent_id=parent,
-                 tid=threading.get_ident())
-        stack.append(s)
-        try:
-            yield s
-        finally:
-            s.dur_ns = self._clock() - s.t0_ns
-            stack.pop()
-            self._record(s)
+        annotation = _profiler().TraceAnnotation(name, **attrs)
+        with annotation:
+            s = Span(name=name, t0_ns=self._clock(), dur_ns=0,
+                     attrs=dict(attrs), span_id=self._alloc(),
+                     parent_id=parent, tid=threading.get_ident(),
+                     annotation=annotation)
+            stack.append(s)
+            try:
+                yield s
+            finally:
+                s.dur_ns = self._clock() - s.t0_ns
+                s.annotation = None
+                stack.pop()
+                self._record(s)
 
     def event(self, name: str, **attrs) -> None:
         """Zero-duration instant event (request lifecycle steps)."""
@@ -122,6 +159,17 @@ class Tracer:
         stack = self._stack()
         if stack:
             stack[-1].attrs.update(attrs)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``python.gc`` span per collection."""
+        if phase == "start":
+            self._gc_open = self.span("python.gc",
+                                      generation=info["generation"])
+            self._gc_open.__enter__()
+        elif self._gc_open is not None:
+            cm, self._gc_open = self._gc_open, None
+            self.attr(collected=info["collected"])
+            cm.__exit__(None, None, None)
 
     # ------------------------------------------------------------------
     def counts(self) -> dict:
@@ -146,6 +194,9 @@ class _NullSpan:
     def __setattr__(self, k, v):      # swallow attr writes
         pass
 
+    def annotate(self, **attrs) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -162,9 +213,14 @@ _ACTIVE: Tracer | None = None
 
 
 def install_tracer(tracer: Tracer) -> Tracer:
-    """Make ``tracer`` the process-global tracer (one at a time)."""
+    """Make ``tracer`` the process-global tracer (one at a time).  An
+    enabled tracer also hooks ``gc.callbacks`` to span each collection
+    until it is uninstalled."""
     global _ACTIVE
+    _unhook_gc(_ACTIVE)
     _ACTIVE = tracer
+    if tracer.enabled:
+        gc.callbacks.append(tracer._on_gc)
     return tracer
 
 
@@ -173,7 +229,13 @@ def uninstall_tracer(tracer: Tracer | None = None) -> None:
     an uninstall racing a newer install then leaves the newer one)."""
     global _ACTIVE
     if tracer is None or _ACTIVE is tracer:
+        _unhook_gc(_ACTIVE)
         _ACTIVE = None
+
+
+def _unhook_gc(tracer: Tracer | None) -> None:
+    if tracer is not None and tracer._on_gc in gc.callbacks:
+        gc.callbacks.remove(tracer._on_gc)
 
 
 def current_tracer() -> Tracer | None:

@@ -448,6 +448,15 @@ def _sample_rows(keys, logits, temperatures):
     return jnp.where(temperatures > 0.0, sampled, greedy)
 
 
+def _jit_step(cfg, **static):
+    """``lm.decode_paged`` bound to ``cfg`` and jitted under its own name,
+    so the compiled program and the profiler's trace call it
+    ``jit_decode_paged`` (a bare ``partial`` has no name)."""
+    def decode_paged(*args, **kwargs):
+        return lm.decode_paged(*args, cfg=cfg, **static, **kwargs)
+    return jax.jit(decode_paged)
+
+
 class PagedServingEngine(_ArchTracedEngine):
     """Continuous batching over a paged KV cache.
 
@@ -532,7 +541,7 @@ class PagedServingEngine(_ArchTracedEngine):
         self._stochastic_substrate = (
             cfg.sc_backend != "exact"
             or getattr(cfg, "paged_attn", "unfused") == "fused_sc")
-        self._step_fn = jax.jit(partial(lm.decode_paged, cfg=cfg))
+        self._step_fn = _jit_step(cfg)
         self._sample_fn = jax.jit(_sample_rows)
         self._copy_fn = jax.jit(attention.paged_copy_blocks)
         if scfg.speculative:
@@ -546,14 +555,13 @@ class PagedServingEngine(_ArchTracedEngine):
             # plain unfused attention: its K/V writes are placeholders the
             # verify pass overwrites, its logits only GUESS tokens.
             dcfg = cfg.replace(sc_backend=dname, paged_attn="unfused")
-            self._draft_fn = jax.jit(partial(lm.decode_paged, cfg=dcfg))
+            self._draft_fn = _jit_step(dcfg)
             # The verifier is the real model at width spec_k+1 returning
             # logits at EVERY fed position (all_logits) — one pass scores
             # the whole drafted run under the exact same per-position key
             # grid as non-speculative decoding, which is what makes its
             # greedy tokens bitwise the non-speculative tokens.
-            self._verify_fn = jax.jit(
-                partial(lm.decode_paged, cfg=cfg, all_logits=True))
+            self._verify_fn = _jit_step(cfg, all_logits=True)
             self._spec_hist = self.metrics.histogram(
                 "spec_accepted_tokens",
                 "draft tokens accepted per speculative row-tick (0..k)",
@@ -612,71 +620,90 @@ class PagedServingEngine(_ArchTracedEngine):
     def step(self):
         """One tick: scheduler plan → one jitted chunked step → sample the
         rows that consumed their pending context.  Returns False when
-        idle.  A raise mid-tick detaches the arch collector."""
-        with self._detach_on_error(), self._device_scope():
-            plan = self.scheduler.plan()
+        idle.  A raise mid-tick detaches the arch collector.
+
+        With an enabled tracer the tick is one ``engine.tick`` span with
+        a child per host phase, in order: ``engine.plan``,
+        ``engine.copy`` (copy-on-write ticks only), ``engine.inputs``,
+        ``engine.step``, ``engine.sample`` (ticks that sample) and
+        ``engine.commit`` — or ``engine.plan``, ``engine.spec``,
+        ``engine.commit`` on a speculative tick."""
+        if not self.scheduler.has_work():
+            return False
+        tr = self.tracer
+        with self._detach_on_error(), self._device_scope(), \
+                tr.span("engine.tick", tick=self.ticks) as tick:
+            with tr.span("engine.plan"):
+                plan = self.scheduler.plan()
             if plan is None:
                 return False
             if not any(plan.n_valid):
                 raise RuntimeError(
                     "scheduler produced a no-progress tick (every row "
                     "deferred) — the block pool is mis-sized")
-            if plan.copies:
-                # copy-on-write: a write this tick lands in a block that
-                # was shared/registered — carry its K/V to the fresh block
-                # before any scatter touches it
-                src = [s for s, _ in plan.copies]
-                dst = [d for _, d in plan.copies]
-                self.pages = self._copy_fn(self.pages, src, dst)
             spec = bool(plan.spec_rows)
             kind = ("spec" if spec
                     else "decode" if plan.sc == 1 else "prefill")
             live = sum(1 for nv in plan.n_valid if nv)
+            tick.annotate(kind=kind, width=plan.sc, live=live, fed=plan.fed,
+                          computed=plan.computed, refed=plan.refed)
             self._m_ticks.inc(kind=kind)
-            with self.tracer.span("engine.tick", tick=self.ticks,
-                                  kind=kind, live=live, width=plan.sc):
-                if spec:
-                    self._run_spec_plan(plan)
-                else:
-                    self._run_plan(plan, live)
+            if plan.copies:
+                # copy-on-write: a write this tick lands in a block that
+                # was shared/registered — carry its K/V to the fresh block
+                # before any scatter touches it
+                with tr.span("engine.copy"):
+                    src = [s for s, _ in plan.copies]
+                    dst = [d for _, d in plan.copies]
+                    self.pages = self._copy_fn(self.pages, src, dst)
+            if spec:
+                self._run_spec_plan(plan)
+            else:
+                self._run_plan(plan, live)
             self.ticks += 1
             return True
 
     def _run_plan(self, plan, live: int):
-        tokens = jnp.asarray(plan.tokens, jnp.int32)
-        lengths = jnp.asarray(plan.lengths, jnp.int32)
-        n_valid = jnp.asarray(plan.n_valid, jnp.int32)
-        tables = jnp.asarray(plan.tables, jnp.int32)
-        rng = jnp.stack(plan.keys) if self._stochastic_substrate else None
-        t0 = time.perf_counter()
-        logits, self.pages = self._step_fn(
-            self.params, self.pages, tables, tokens, lengths, n_valid,
-            rng=rng)
-        if plan.sc == 1:
-            # decode tick: force completion so the wall time covers
-            # the device step, then normalize per live row.  The first
-            # decode tick is the jit compile — count it, don't time it.
-            logits.block_until_ready()
-            ms = (time.perf_counter() - t0) * 1e3 / max(live, 1)
-            if self._seen_decode_tick:
-                self._decode_hist.observe(ms)
-            else:
-                self._seen_decode_tick = True
-                self._m_jit_ticks.inc()
-            self.tracer.attr(decode_ms_per_token=round(ms, 4))
+        tr = self.tracer
+        with tr.span("engine.inputs"):
+            tokens = jnp.asarray(plan.tokens, jnp.int32)
+            lengths = jnp.asarray(plan.lengths, jnp.int32)
+            n_valid = jnp.asarray(plan.n_valid, jnp.int32)
+            tables = jnp.asarray(plan.tables, jnp.int32)
+            rng = (jnp.stack(plan.keys) if self._stochastic_substrate
+                   else None)
+        with tr.span("engine.step"):
+            t0 = time.perf_counter()
+            logits, self.pages = self._step_fn(
+                self.params, self.pages, tables, tokens, lengths, n_valid,
+                rng=rng)
+            if plan.sc == 1:
+                # decode tick: force completion so the wall time covers
+                # the device step, then normalize per live row.  The first
+                # decode tick is the jit compile — count it, don't time it.
+                logits.block_until_ready()
+                ms = (time.perf_counter() - t0) * 1e3 / max(live, 1)
+                if self._seen_decode_tick:
+                    self._decode_hist.observe(ms)
+                else:
+                    self._seen_decode_tick = True
+                    self._m_jit_ticks.inc()
+        toks = None
         if plan.sample_rows:
             # One batched sampling call + one host sync per tick: the
             # (slots, vocab) shapes are tick-invariant, so this stays
             # a single compiled executable.  Non-sampling slots get
             # dummy keys and their outputs are discarded.
-            keys = [self._dummy_sample_key()] * len(plan.tokens)
-            temps = [0.0] * len(plan.tokens)
-            for slot, seq in plan.sample_rows:
-                keys[slot] = self.scheduler.sample_key(seq)
-                temps[slot] = seq.req.temperature
-            toks = np.asarray(self._sample_fn(
-                jnp.stack(keys), logits,
-                jnp.asarray(temps, jnp.float32))).tolist()   # one sync
+            with tr.span("engine.sample"):
+                keys = [self._dummy_sample_key()] * len(plan.tokens)
+                temps = [0.0] * len(plan.tokens)
+                for slot, seq in plan.sample_rows:
+                    keys[slot] = self.scheduler.sample_key(seq)
+                    temps[slot] = seq.req.temperature
+                toks = np.asarray(self._sample_fn(
+                    jnp.stack(keys), logits,
+                    jnp.asarray(temps, jnp.float32))).tolist()   # one sync
+        with tr.span("engine.commit"):
             for slot, seq in plan.sample_rows:
                 self.scheduler.on_token(slot, seq, toks[slot])
 
@@ -709,6 +736,33 @@ class PagedServingEngine(_ArchTracedEngine):
         single token (``n_valid`` from the plan) and sample from its
         position-0 logits — a mixed batch costs no extra dispatch.
         """
+        tr = self.tracer
+        with tr.span("engine.spec"):
+            drafts, greedy, toks = self._draft_and_verify(plan)
+        k = self.scheduler.spec_k
+        with tr.span("engine.commit"):
+            for slot, seq in plan.spec_rows:
+                vrow = [int(t) for t in greedy[slot]]
+                a = 0
+                while a < k and drafts[slot][a] == vrow[a]:
+                    a += 1
+                committed = self.scheduler.on_tokens(slot, seq,
+                                                     vrow[:a + 1])
+                self._spec_hist.observe(float(a))
+                self._m_spec_drafted.inc(k)
+                self._m_spec_accepted.inc(a)
+                self.spec_log.append(dict(
+                    tick=self.ticks, rid=seq.req.rid, k=k,
+                    drafted=list(drafts[slot]), verified=vrow,
+                    accepted=a, committed=committed))
+            for slot, seq in plan.sample_rows:
+                self.scheduler.on_token(slot, seq, toks[slot])
+
+    def _draft_and_verify(self, plan):
+        """The device half of a speculative tick: ``spec_k`` draft steps,
+        the verify pass and the sampler for rows not speculating.
+        Returns (drafts per row, the verifier's greedy tokens (b, k+1),
+        sampled tokens or None)."""
         k = self.scheduler.spec_k
         b = len(plan.tokens)
         lengths = jnp.asarray(plan.lengths, jnp.int32)
@@ -768,19 +822,7 @@ class PagedServingEngine(_ArchTracedEngine):
             self.params, self.pages, tables, jnp.asarray(vtok, jnp.int32),
             lengths, jnp.asarray(vnv, jnp.int32), rng=rng)
         greedy = np.asarray(jnp.argmax(vlogits, axis=-1))   # (b, k+1), sync
-        for slot, seq in plan.spec_rows:
-            vrow = [int(t) for t in greedy[slot]]
-            a = 0
-            while a < k and drafts[slot][a] == vrow[a]:
-                a += 1
-            committed = self.scheduler.on_tokens(slot, seq, vrow[:a + 1])
-            self._spec_hist.observe(float(a))
-            self._m_spec_drafted.inc(k)
-            self._m_spec_accepted.inc(a)
-            self.spec_log.append(dict(
-                tick=self.ticks, rid=seq.req.rid, k=k,
-                drafted=list(drafts[slot]), verified=vrow,
-                accepted=a, committed=committed))
+        toks = None
         if plan.sample_rows:
             keys = [self._dummy_sample_key()] * b
             temps = [0.0] * b
@@ -790,8 +832,7 @@ class PagedServingEngine(_ArchTracedEngine):
             toks = np.asarray(self._sample_fn(
                 jnp.stack(keys), vlogits[:, 0],
                 jnp.asarray(temps, jnp.float32))).tolist()
-            for slot, seq in plan.sample_rows:
-                self.scheduler.on_token(slot, seq, toks[slot])
+        return drafts, greedy, toks
 
     # ------------------------------------------------------------------
     # Drain / resume (ft.FleetSupervisor's shard-failover contract)

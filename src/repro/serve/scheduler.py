@@ -89,6 +89,11 @@ class Sequence:
     # only; extended lazily).  ckeys[t] is a function of tokens[0..t] and
     # the engine seed alone, so it survives eviction/resume unchanged.
     ckeys: list = dataclasses.field(default_factory=list)
+    # High-water mark: one past the highest position ever fed.  Eviction
+    # leaves it as it is, so a feed below it is a position fed again
+    # (counted as re-fed); positions a resumed row re-adopts from the
+    # prefix cache are never fed, so they never count.
+    high: int = 0
 
     @property
     def context_len(self) -> int:
@@ -124,6 +129,12 @@ class TickPlan:
     # [(slot, Sequence)] rows the engine should draft+verify this tick
     # (their pool span through fed + spec_k is reserved and writable)
     spec_rows: list = dataclasses.field(default_factory=list)
+    # positions fed for requests (sum of n_valid), positions the step
+    # computes (slots x sc), and fed positions below their row's
+    # high-water mark (fed again after an eviction)
+    fed: int = 0
+    computed: int = 0
+    refed: int = 0
 
 
 class Scheduler:
@@ -178,6 +189,17 @@ class Scheduler:
             "prefix-cache hits never reach here)")
         self._m_generated = m.counter(
             "serve_tokens_generated_total", "tokens sampled across requests")
+        self._m_fed = m.counter(
+            "serve_fed_positions_total",
+            "positions fed for requests (prompt, re-fed context and "
+            "fed-back tokens; chunk padding and idle rows excluded)")
+        self._m_step_pos = m.counter(
+            "serve_step_positions_total",
+            "positions the step computes: slots x tick width, per tick")
+        self._m_refed = m.counter(
+            "serve_refed_positions_total",
+            "fed positions below their row's high-water mark: context fed "
+            "again after an eviction")
         self._g_queue = m.gauge("serve_queue_depth", "requests waiting")
         self._g_active = m.gauge(
             "serve_active_requests", "requests holding a batch row")
@@ -208,6 +230,7 @@ class Scheduler:
         ``_admit``'s bookkeeping handles it unchanged — ``adopt_prefix``
         no-ops on an existing table and ``ensure``/``has_room`` extend
         it."""
+        seq.high = max(seq.high, seq.fed)
         self.waiting.appendleft(seq)
         self._m_submitted.inc()
         self._update_gauges()
@@ -234,6 +257,7 @@ class Scheduler:
             if victim is keep:
                 continue
             slot = self.rows.index(victim)
+            fed = victim.fed
             self.kv.release(victim.req.rid)
             self.rows[slot] = None
             self.admit_stack.remove(victim)
@@ -243,7 +267,7 @@ class Scheduler:
             self._m_evicted.inc()
             self._update_gauges()
             self.tracer.event("request.evict", rid=victim.req.rid,
-                              generated=len(victim.req.generated))
+                              generated=len(victim.req.generated), fed=fed)
             return slot
         return None
 
@@ -372,6 +396,7 @@ class Scheduler:
                 spec_slots.add(slot)
         tokens, lengths, n_valid, tables, keys = [], [], [], [], []
         sample_rows, spec_rows = [], []
+        refed = 0
         for slot in range(self.scfg.slots):
             seq = self.rows[slot]
             n = planned.get(slot, 0)
@@ -388,7 +413,9 @@ class Scheduler:
             lengths.append(seq.fed)
             n_valid.append(n)
             keys.append(self._row_keys(seq, n, sc))
+            refed += max(0, min(seq.fed + n, seq.high) - seq.fed)
             seq.fed += n
+            seq.high = max(seq.high, seq.fed)
             tables.append(self.kv.table_row(seq.req.rid))
             if n and seq.prefilling:
                 self._m_prefill_tok.inc(n)
@@ -404,10 +431,15 @@ class Scheduler:
                     spec_rows.append((slot, seq))
                 else:
                     sample_rows.append((slot, seq))
+        fed, computed = sum(n_valid), self.scfg.slots * sc
+        self._m_fed.inc(fed)
+        self._m_step_pos.inc(computed)
+        self._m_refed.inc(refed)
         return TickPlan(sc=sc, tokens=tokens, lengths=lengths,
                         n_valid=n_valid, tables=tables, keys=keys,
                         sample_rows=sample_rows, copies=copies,
-                        spec_rows=spec_rows)
+                        spec_rows=spec_rows, fed=fed, computed=computed,
+                        refed=refed)
 
     # ------------------------------------------------------------------
     def sample_key(self, seq: Sequence):
@@ -432,6 +464,7 @@ class Scheduler:
                 # the PREVIOUS committed token's KV was written by the
                 # verify pass at position fed — advance past it
                 seq.fed += 1
+                seq.high = max(seq.high, seq.fed)
             seq.req.generated.append(token)
             self._m_generated.inc()
             hit_eos = token == self.scfg.eos_id

@@ -8,6 +8,8 @@ exposition, and a trace JSONL whose span counts MATCH the engine's
 lifecycle events exactly.
 """
 
+import gc
+import glob
 import json
 import math
 import os
@@ -208,6 +210,54 @@ def test_install_tracer_slot():
     assert obs.current_tracer() is None
 
 
+def test_tracer_span_reaches_the_profiler_trace(tmp_path):
+    """An enabled tracer's span comes back from a CPU profiler trace
+    under its name, with the attributes given at open and annotated
+    before it closed as stats; ``attr`` stays in memory only."""
+    from jax.profiler import ProfileData
+    tr = obs.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("engine.tick", kind="prefill", live=8) as s:
+            s.annotate(fed=3)
+            tr.attr(note="memory only")
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    events = [e for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/host:") for line in p.lines
+              for e in line.events if e.name == "engine.tick"]
+    assert len(events) == 1 and events[0].duration_ns > 0
+    assert list(events[0].stats) == [("kind", "prefill"), ("live", 8),
+                                     ("fed", 3)]
+    assert tr.spans[0].attrs == {"kind": "prefill", "live": 8, "fed": 3,
+                                 "note": "memory only"}
+    assert tr.spans[0].annotation is None          # closed with the span
+
+
+def test_installed_tracer_spans_garbage_collections():
+    """An installed enabled tracer records a python.gc span per
+    collection; uninstalling removes the hook, and a disabled tracer
+    never adds one."""
+    tr = obs.install_tracer(obs.Tracer())
+    try:
+        gc.collect()
+    finally:
+        obs.uninstall_tracer(tr)
+    spans = [s for s in tr.spans if s.name == "python.gc"]
+    assert spans and spans[-1].attrs["generation"] == 2
+    assert spans[-1].attrs["collected"] >= 0
+    n = len(tr.spans)
+    gc.collect()
+    assert len(tr.spans) == n
+    off = obs.install_tracer(obs.Tracer(enabled=False))
+    try:
+        assert off._on_gc not in gc.callbacks
+    finally:
+        obs.uninstall_tracer(off)
+    assert tr._on_gc not in gc.callbacks
+
+
 # ---------------------------------------------------------------------------
 # Substrate hooks: sc dispatch, autotune, arch pricing (default-off)
 # ---------------------------------------------------------------------------
@@ -358,12 +408,14 @@ def test_engine_emits_matching_spans_and_counters(serve_setup, tmp_path):
         metrics.value("serve_kv_blocks_freed_total") > 0
     assert metrics.value("serve_queue_depth") == 0
     assert metrics.value("serve_active_requests") == 0
-    # tick spans carry kind/live/width attrs; decode ticks the wall time
+    # tick spans carry kind/live/width attrs; a decode tick feeds one
+    # position per live row and computes one per slot
     ticks = [s for s in tracer.spans if s.name == "engine.tick"]
     assert all(s.attrs["kind"] in ("prefill", "decode") for s in ticks)
     decode = [s for s in ticks if s.attrs["kind"] == "decode"]
-    assert decode and all("decode_ms_per_token" in s.attrs for s in decode)
-    assert all(s.attrs["width"] == 1 for s in decode)
+    assert decode and all(s.attrs["fed"] == s.attrs["live"] for s in decode)
+    assert all(s.attrs["width"] == 1 and s.attrs["computed"] == 2
+               for s in decode)
     # the jit tick is excluded from the histogram but counted
     assert metrics.value("serve_decode_jit_ticks_total") == 1
     assert metrics.histogram("serve_decode_ms_per_token").count() == \
@@ -407,6 +459,116 @@ def test_engine_eviction_spans_and_counters(serve_setup):
     resumed = [s for s in tracer.spans
                if s.name == "request.admit" and s.attrs["resumed"]]
     assert len(resumed) == eng.evictions
+
+
+ENGINE_CHILDREN = ("engine.plan", "engine.copy", "engine.inputs",
+                   "engine.step", "engine.sample", "engine.commit")
+
+
+def _ticks_and_children(tracer):
+    ticks = [s for s in tracer.spans if s.name == "engine.tick"]
+    kids = {t.span_id: [] for t in ticks}
+    for s in tracer.spans:
+        if s.name in ENGINE_CHILDREN:
+            kids[s.parent_id].append(s)      # KeyError: not under a tick
+    return ticks, kids
+
+
+def test_engine_tick_phases_nest_in_order(serve_setup):
+    """Every tick of a drained run is one engine.tick span whose
+    children are its host phases, in order, inside it and disjoint."""
+    cfg, params = serve_setup
+    tracer = obs.Tracer()
+    eng = _drain(params, cfg, _requests(3), tracer=tracer)
+    ticks, kids = _ticks_and_children(tracer)
+    assert len(ticks) == eng.ticks > 0
+    sampled = 0
+    for t in ticks:
+        phases = sorted(kids[t.span_id], key=lambda s: s.t0_ns)
+        names = [s.name for s in phases]
+        assert names == [n for n in ENGINE_CHILDREN if n in names]
+        assert {"engine.plan", "engine.inputs", "engine.step",
+                "engine.commit"} <= set(names)
+        assert "engine.copy" not in names           # no prefix cache
+        sampled += "engine.sample" in names
+        assert phases[0].t0_ns >= t.t0_ns
+        assert phases[-1].t0_ns + phases[-1].dur_ns <= t.t0_ns + t.dur_ns
+        for a, b in zip(phases, phases[1:]):
+            assert a.t0_ns + a.dur_ns <= b.t0_ns
+    # every decode tick samples; a prefill tick samples when a prompt ends
+    decode = sum(t.attrs["kind"] == "decode" for t in ticks)
+    assert decode <= sampled <= len(ticks)
+    # an idle engine records no tick
+    assert not eng.step() and tracer.counts()["engine.tick"] == len(ticks)
+
+
+def test_tick_attributes_sum_to_the_position_counters(serve_setup):
+    """fed/computed/refed on the tick spans add up to the scheduler's
+    counters; with no eviction every position is fed once."""
+    cfg, params = serve_setup
+    metrics, tracer = obs.MetricsRegistry(), obs.Tracer()
+    eng = _drain(params, cfg, _requests(3), metrics=metrics, tracer=tracer)
+    ticks = [s for s in tracer.spans if s.name == "engine.tick"]
+    for attr, counter in (("fed", "serve_fed_positions_total"),
+                          ("computed", "serve_step_positions_total"),
+                          ("refed", "serve_refed_positions_total")):
+        assert sum(t.attrs[attr] for t in ticks) == metrics.value(counter)
+    # the last token of a request is sampled, never fed
+    assert metrics.value("serve_fed_positions_total") == sum(
+        len(r.prompt) + len(r.generated) - 1 for r in eng.finished)
+    assert metrics.value("serve_step_positions_total") == sum(
+        2 * t.attrs["width"] for t in ticks)
+    assert metrics.value("serve_refed_positions_total") == 0
+
+
+def test_evicted_rows_count_their_fed_positions_as_refed(serve_setup):
+    """In the forced-eviction geometry, the re-fed positions are exactly
+    those the evicted rows had fed, and every other position is fed
+    once."""
+    cfg, params = serve_setup
+    metrics, tracer = obs.MetricsRegistry(), obs.Tracer()
+    reqs = [Request(rid=i, prompt=[7 + i] * 8, max_new_tokens=12,
+                    temperature=0.0) for i in range(2)]
+    eng = _drain(params, cfg, reqs, prefill_chunk=4, metrics=metrics,
+                 tracer=tracer, num_blocks=9)
+    evicts = [s for s in tracer.spans if s.name == "request.evict"]
+    assert len(evicts) == eng.evictions > 0
+    refed = metrics.value("serve_refed_positions_total")
+    assert refed == sum(s.attrs["fed"] for s in evicts) > 0
+    assert metrics.value("serve_fed_positions_total") - refed == sum(
+        len(r.prompt) + len(r.generated) - 1 for r in eng.finished)
+
+
+def test_engine_without_a_tracer_opens_no_profiler_annotation(
+        serve_setup, monkeypatch):
+    """The default engine (NULL_TRACER) constructs no TraceAnnotation;
+    the same run with an enabled tracer does."""
+    from jax import profiler
+    cfg, params = serve_setup
+    made = []
+
+    class Counting(profiler.TraceAnnotation):
+        def __init__(self, name, **kwargs):
+            made.append(name)
+            super().__init__(name, **kwargs)
+
+    monkeypatch.setattr(profiler, "TraceAnnotation", Counting)
+    eng = _drain(params, cfg, _requests(2))
+    assert eng.tracer is obs.NULL_TRACER and made == []
+    _drain(params, cfg, _requests(2), tracer=obs.Tracer())
+    assert "engine.tick" in made and "engine.step" in made
+
+
+def test_engine_step_is_jitted_under_its_name(serve_setup):
+    """The trace names the step ``jit_decode_paged``, as the benchmark's
+    step readers expect."""
+    cfg, params = serve_setup
+    eng = _drain(params, cfg, [])
+    table = jnp.zeros((2, 8), jnp.int32)
+    text = eng._step_fn.lower(
+        params, eng.pages, table, jnp.zeros((2, 1), jnp.int32),
+        jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32)).as_text()
+    assert text.startswith("module @jit_decode_paged")
 
 
 @pytest.mark.parametrize("max_new,expect_none", [(2, True), (3, True),

@@ -85,6 +85,7 @@ def test_sc_fused_rows_mode_compiles(chip, site, n):
         _spec(chip, (ROWS, D), jnp.float32),
         _spec(chip, (D, n), jnp.float32))
     assert "tpu_custom_call" in text, site
+    assert "%sc_fused_popcount." in text, site
 
 
 def test_sc_mul_compiles(chip):
@@ -116,16 +117,22 @@ def _attn_args(chip):
 
 def test_paged_attention_fused_compiles(chip):
     q, pages, table, lengths = _attn_args(chip)
-    assert "tpu_custom_call" in _compiled_text(
+    text = _compiled_text(
         pa.paged_attention_fused, q, pages, pages, table, lengths)
+    assert "tpu_custom_call" in text
+    assert "%paged_attention." in text        # the kernel's name
+    assert "sc_fused_popcount" not in text
 
 
 def test_paged_attention_fused_sc_compiles(chip):
     q, pages, table, lengths = _attn_args(chip)
     keys = _spec(chip, (SLOTS, 1, 2), jnp.uint32)
-    assert "tpu_custom_call" in _compiled_text(
+    text = _compiled_text(
         lambda *a: pa.paged_attention_fused_sc(*a, nbit=NBIT),
         keys, q, pages, pages, table, lengths)
+    assert "tpu_custom_call" in text
+    assert "%paged_attention_sc." in text
+    assert "sc_fused_popcount" not in text
 
 
 def test_decode_paged_exact_step_compiles(chip):
@@ -145,7 +152,14 @@ def test_decode_paged_exact_step_compiles(chip):
         _spec(chip, (SLOTS, 1), jnp.int32),
         _spec(chip, (SLOTS,), jnp.int32),
         _spec(chip, (SLOTS,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the attention kernel and every model site are named in the program
+    # (and so in the profiler's trace)
+    assert "%paged_attention." in text
+    for scope in ("qkv", "attn", "attn_out", "mlp_in", "mlp_out",
+                  "unembed"):
+        assert f"/{scope}/" in text, scope
     # the step fits one chip's 16 GB with room for the engine around it
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
