@@ -59,7 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import kernels
-from repro.kernels.sc_fused import encode_fx16, word_counts
+from repro.kernels.sc_fused import encode_fx16, ladder_start, word_counts
 from repro.kernels.sc_mul import LANE_BITS
 from repro.sc import autotune, ctr_rng
 
@@ -211,7 +211,8 @@ def _sc_logits(q_blk, k_blk, keys4, t_abs, head, *, n_heads, nbit,
             (ta * n_heads + head) * hd + d_idx, jnp.uint32)  # (bq, hd)
         counts = word_counts(
             kq, kk, c0, fxq, jnp.broadcast_to(pk, (bq, hd)),
-            nwords=nwords, lane_words=lane)
+            nwords=nwords, lane_words=lane,
+            start=ladder_start(levels, quantize))
         total = jnp.sum(sgq * sk * counts, axis=1, keepdims=True)
         est = total.astype(jnp.float32) / jnp.float32(nbit) * scq * sck_t
         return jnp.where(out_lane == t, est * _scale(hd), logits)

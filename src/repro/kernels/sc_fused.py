@@ -17,7 +17,12 @@ over K.  This kernel collapses all of it into ONE ``pallas_call``:
   host (same key ⇒ same bits, whatever the autotuner picked);
 * **MTJ write-probability thresholding** — the Horner bit-ladder of
   ``kernels/sc_mul.py`` turns uniform words into packed Bernoulli cells,
-  32 per lane word (the row-parallel stochastic write);
+  32 per lane word (the row-parallel stochastic write).  The ladder
+  starts at the first slice a bias word can set (``ladder_start``): on
+  a quantized 2^n grid the low 16-n bits of every word are zero and the
+  slices below them provably leave the cells at zero, so they are not
+  drawn.  Every slice that is drawn keeps its pinned counter, so the
+  bits are those of the full 16-slice ladder;
 * **pop-count accumulation** — two-pulse AND + SWAR pop-count, then a
   *signed integer* accumulation over the K grid axis in a VMEM scratch
   accumulator.
@@ -86,7 +91,24 @@ def _splat(scalar, shape):
     return _u32(jnp.full(shape, scalar, jnp.int32))
 
 
-def word_counts(kx, ky, pid, px, pw, *, nwords: int, lane_words: int):
+def ladder_start(levels: int, quantize: bool) -> int:
+    """First Horner slice that can set a cell, for a static operand grid.
+
+    A quantized operand on a power-of-two grid of ``levels`` <= 2^16 is
+    ``i / levels``, whose fx16 word ``i · 2^16 / levels`` has its low
+    ``16 - log2(levels)`` bits zero (``encoding.to_fx16`` is exact
+    there).  The ladder runs from the LSB with the cells at zero, and a
+    zero mask bit keeps them at zero (``ux & 0``), so those slices add
+    nothing.  Unquantized operands and other grids start at slice 0.
+    """
+    if quantize and 0 < levels <= encoding.FX16_ONE \
+            and levels & (levels - 1) == 0:
+        return NSLICES - (levels.bit_length() - 1)
+    return 0
+
+
+def word_counts(kx, ky, pid, px, pw, *, nwords: int, lane_words: int,
+                start: int):
     """Pop-count of the cells surviving the two-pulse AND, over all words.
 
     kx / ky: (k0, k1) uint32 key pairs of the x and w operand streams;
@@ -95,6 +117,10 @@ def word_counts(kx, ky, pid, px, pw, *, nwords: int, lane_words: int):
     ``threefry(key, (pid, s·nwords + w))[0]`` — the pinned layout of
     ``ctr_rng.product_counters``.  ``lane_words`` words are unrolled per
     loop step; integer sums are associative, so it never changes bits.
+    The ladder runs slices ``start``..15 (``ladder_start``): the caller
+    vouches that bits below ``start`` of every bias word are zero, and
+    each slice drawn keeps its absolute counter, so the counts equal
+    those of a ladder from slice 0.
     """
     shape = pid.shape
 
@@ -103,7 +129,7 @@ def word_counts(kx, ky, pid, px, pw, *, nwords: int, lane_words: int):
             w = g * lane_words + u
             tx = jnp.zeros(shape, jnp.uint32)
             ty = jnp.zeros(shape, jnp.uint32)
-            for s in range(NSLICES):           # LSB -> MSB Horner ladder
+            for s in range(start, NSLICES):    # LSB -> MSB Horner ladder
                 c1 = _splat(s * nwords + w, shape)
                 ux = ctr_rng.threefry2x32(kx[0], kx[1], pid, c1)[0]
                 uy = ctr_rng.threefry2x32(ky[0], ky[1], pid, c1)[0]
@@ -170,7 +196,8 @@ def _sc_fused_kernel(keys_ref, xt_ref, w_ref, out_ref, acc_ref, *,
                     * jnp.sign(ws).astype(jnp.int32))
             pid = base + _splat(k0, shape) * jnp.uint32(n_orig)
             counts = word_counts(kx, ky, pid, px, pw, nwords=nwords,
-                                 lane_words=lane_words)
+                                 lane_words=lane_words,
+                                 start=ladder_start(levels, quantize))
             return part + sign * counts
 
         part = jax.lax.fori_loop(0, bk // SUBLANES, kchunk,

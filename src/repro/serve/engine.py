@@ -200,6 +200,23 @@ class _ArchTracedEngine:
         self._m_errors = self.metrics.counter(
             "serve_errors_total", "engine ticks that raised")
 
+    def _set_sc_ladder_gauge(self, cfg, fused_sc_attention: bool) -> None:
+        """``serve_sc_ladder_slices``: Horner slices the fused SC pop-count
+        core (``kernels/sc_fused.py:word_counts``) draws per word, set
+        once at build when a dense site runs ``pallas_fused`` or attention
+        runs ``fused_sc``; engines that run neither leave it unset."""
+        from repro import sc
+        from repro.kernels import sc_fused
+        if not (fused_sc_attention or sc.fast_backend(
+                cfg.sc_backend, cfg.sc_nbit) == "pallas_fused"):
+            return
+        grid = sc.ScConfig()        # the operand grid both call sites use
+        self.metrics.gauge(
+            "serve_sc_ladder_slices",
+            "Horner slices drawn per SC word (of 16)").set(
+                sc_fused.NSLICES - sc_fused.ladder_start(
+                    1 << grid.operand_bits, grid.quantize))
+
     def arch_report(self):
         """Aggregate arch cost of everything compiled so far (None when
         trace collection is off or nothing was recorded). NOTE: the
@@ -287,6 +304,7 @@ class ServingEngine(_ArchTracedEngine):
             partial(lm.prefill, cfg=cfg, max_len=scfg.max_len))
         self._init_arch(collect_arch_trace, cfg)
         self._init_obs(metrics, tracer)
+        self._set_sc_ladder_gauge(cfg, fused_sc_attention=False)
         self._m_submitted = self.metrics.counter(
             "serve_requests_submitted_total", "requests entering the queue")
         self._m_finished = self.metrics.counter(
@@ -538,9 +556,11 @@ class PagedServingEngine(_ArchTracedEngine):
             tracer=self.tracer)
         # fused_sc attention draws per-token stochastic logits even when
         # the dense substrate is exact, so it needs per-request keys too
+        fused_sc_attention = (
+            getattr(cfg, "paged_attn", "unfused") == "fused_sc")
         self._stochastic_substrate = (
-            cfg.sc_backend != "exact"
-            or getattr(cfg, "paged_attn", "unfused") == "fused_sc")
+            cfg.sc_backend != "exact" or fused_sc_attention)
+        self._set_sc_ladder_gauge(cfg, fused_sc_attention)
         self._step_fn = _jit_step(cfg)
         self._sample_fn = jax.jit(_sample_rows)
         self._copy_fn = jax.jit(attention.paged_copy_blocks)

@@ -185,6 +185,28 @@ def test_sc_kernel_matches_host_twin_bitwise():
     assert np.array_equal(np.concatenate(parts), np.asarray(host))
 
 
+@pytest.mark.parametrize("operand_bits", [4, 10])
+def test_sc_logits_live_ladder_equals_full_ladder(monkeypatch,
+                                                  operand_bits):
+    """``_sc_logits`` draws only the slices its operand grid leaves live
+    (``sc_fused.ladder_start``) and still equals the full 16-slice
+    ladder bit for bit — the host twin shares its body, so it cannot be
+    this witness."""
+    rng = np.random.default_rng(9)
+    hd, bs, h = 8, 4, 4
+    q = jnp.asarray(rng.normal(size=(2, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(bs, hd)), jnp.float32)
+    keys4 = pa.split_keys4(_token_keys(jax.random.PRNGKey(10), 1, 2)[0])
+    args = (q, k, keys4, jnp.arange(bs, dtype=jnp.int32)[None],
+            jnp.asarray([[1], [3]], jnp.int32))
+    kw = dict(n_heads=h, nbit=128, levels=1 << operand_bits,
+              quantize=True, lane=1)
+    live = np.asarray(pa._sc_logits.__wrapped__(*args, **kw))
+    monkeypatch.setattr(pa, "ladder_start", lambda levels, quantize: 0)
+    full = np.asarray(pa._sc_logits.__wrapped__(*args, **kw))
+    assert np.array_equal(live, full)
+
+
 def test_sc_tiling_never_changes_bits():
     rng = np.random.default_rng(7)
     b, sc = 2, 3
@@ -424,6 +446,26 @@ def test_engine_records_decode_latency(key):
     fresh = PagedServingEngine(params, cfg, PagedServeConfig(
         slots=1, max_len=32, block_size=4, prefill_chunk=3))
     assert fresh.decode_latency_ms() is None
+
+
+@pytest.mark.parametrize("overrides,slices", [
+    (dict(), None),
+    (dict(paged_attn="fused"), None),
+    (dict(sc_backend="moment", sc_nbit=64), None),
+    (dict(paged_attn="fused_sc", sc_nbit=64), 10),
+    (dict(sc_backend="pallas_fused", sc_nbit=64), 10),
+    (dict(sc_backend="pallas_bitexact", sc_nbit=64,
+          paged_attn="fused_sc"), 10),
+])
+def test_engine_sets_sc_ladder_gauge(key, overrides, slices):
+    """An engine whose model runs the fused SC ladder reports the slices
+    it draws per word (10 of 16 on the 10-bit grid); others leave the
+    gauge unset."""
+    cfg = _cfg(**overrides)
+    params = P.init_params(key, lm.lm_param_specs(cfg), cfg.param_dtype)
+    eng = PagedServingEngine(params, cfg, PagedServeConfig(
+        slots=1, max_len=32, block_size=4, prefill_chunk=3))
+    assert eng.metrics.value("serve_sc_ladder_slices") == slices
 
 
 def test_engine_fused_sc_batch_composition_invariance(key):

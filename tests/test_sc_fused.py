@@ -37,17 +37,62 @@ def _xw(key, m, k, n):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("operand_bits", [4, 6, 8])
+@pytest.mark.parametrize(
+    "operand_bits,quantize",
+    [(4, True), (6, True), (8, True), (10, True), (16, True), (10, False)],
+    ids=["4", "6", "8", "10", "16", "10-unquantized"])
 @pytest.mark.parametrize("m,k,n", [(8, 32, 8), (5, 17, 3), (1, 9, 13)])
-def test_fused_bit_equals_packed(key, operand_bits, m, k, n):
+def test_fused_bit_equals_packed(key, operand_bits, quantize, m, k, n):
     """Same key => same bits as pallas_bitexact, across operand grids and
-    ragged (non-block-multiple) shapes."""
+    ragged (non-block-multiple) shapes.  The fused ladder skips the slices
+    a quantized grid leaves dead (none at 16 bits or unquantized); the
+    packed path draws all 16, so this is the witness that they are."""
     x, w = _xw(key, m, k, n)
-    kw = dict(nbit=_NBIT, operand_bits=operand_bits)
+    kw = dict(nbit=_NBIT, operand_bits=operand_bits, quantize=quantize)
     yb = sc.sc_dot(key, x, w,
                    sc.ScConfig(backend="pallas_bitexact", **kw))
     yf = sc.sc_dot(key, x, w, sc.ScConfig(backend="pallas_fused", **kw))
     np.testing.assert_array_equal(np.asarray(yb), np.asarray(yf))
+
+
+@pytest.mark.parametrize("levels,quantize,start", [
+    (1024, True, 6), (16, True, 12), (65536, True, 0), (1024, False, 0),
+    (1000, True, 0)])
+def test_ladder_start(levels, quantize, start):
+    from repro.kernels import sc_fused
+    assert sc_fused.ladder_start(levels, quantize) == start
+
+
+@pytest.mark.parametrize("lane_words", [1, 4])
+def test_word_counts_live_slices_equal_full_ladder(lane_words):
+    """On random quantized slabs (three (8, 128) slabs stacked) the ladder
+    from ``ladder_start`` pop-counts exactly what the full 16-slice
+    ladder does."""
+    from repro.kernels import sc_fused
+    levels = 1 << sc.ScConfig().operand_bits
+    start = sc_fused.ladder_start(levels, True)
+    assert start > 0
+    rng = np.random.default_rng(lane_words)
+    shape = (3 * sc_fused.SUBLANES, sc_fused.LANES)
+
+    def u32():
+        return jnp.asarray(rng.integers(0, 2 ** 32, size=shape,
+                                        dtype=np.uint64), jnp.uint32)
+
+    def fx16():
+        return sc_fused.encode_fx16(
+            jnp.asarray(rng.random(shape), jnp.float32), levels, True)
+
+    kx, ky, pid, px, pw = (u32(), u32()), (u32(), u32()), u32(), fx16(), \
+        fx16()
+    assert not np.any(np.asarray(px | pw) & ((1 << start) - 1))
+    counts = jax.jit(sc_fused.word_counts,
+                     static_argnames=("nwords", "lane_words", "start"))
+    full, live = (np.asarray(counts(kx, ky, pid, px, pw, nwords=8,
+                                    lane_words=lane_words, start=s))
+                  for s in (0, start))
+    assert full.any()
+    np.testing.assert_array_equal(full, live)
 
 
 def test_fused_differs_across_keys(key):
