@@ -41,7 +41,7 @@ from repro.serve.kv_cache import CachePlan
 # embeddings frontend (frontend_proj site) on top of plain attention
 FAMILIES = {
     "dense": "qwen2-0.5b",
-    "moe": "moonshot-v1-16b-a3b",
+    "moe": "moonlight-16b-a3b",
     "ssm": "mamba2-370m",
     "hybrid": "zamba2-7b",
     "multimodal": "musicgen-large",

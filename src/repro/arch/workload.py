@@ -66,14 +66,24 @@ def dense_workload(cfg, tokens: int) -> list[MatmulSite]:
             _attn_sites(add, d, h, kvh, hd, n_shared, prefix="shared.")
             _mlp_sites(add, cfg, n_shared, prefix="shared.")
     else:
-        _attn_sites(add, d, h, kvh, hd, n_layers)
+        if cfg.mla:
+            _mla_sites(add, cfg, n_layers)
+        else:
+            _attn_sites(add, d, h, kvh, hd, n_layers)
         if cfg.family == "moe":
-            # Router + top_k expert FFN visits per token (dense equivalents).
-            add("moe.router", d, cfg.n_experts, n_layers)
-            visits = cfg.top_k + (1 if cfg.shared_expert else 0)
-            wi_cols = 2 * cfg.d_ff if cfg.mlp_variant == "swiglu" else cfg.d_ff
-            add("moe.wi", d, wi_cols, n_layers * visits)
-            add("moe.wo", cfg.d_ff, d, n_layers * visits)
+            # leading dense layers, then router + top_k routed expert
+            # visits per token (dense equivalents) + the shared experts
+            n_moe = lm.n_backbone_layers(cfg)
+            if cfg.first_k_dense:
+                _mlp_sites(add, cfg, cfg.first_k_dense)
+            f = cfg.expert_d_ff
+            add("moe.router", d, cfg.n_experts, n_moe)
+            add("moe.wi", d, 2 * f, n_moe * cfg.top_k)
+            add("moe.wo", f, d, n_moe * cfg.top_k)
+            if cfg.n_shared_experts:
+                fs = cfg.n_shared_experts * f
+                add("moe.shared.wi", d, 2 * fs, n_moe)
+                add("moe.shared.wo", fs, d, n_moe)
         else:
             _mlp_sites(add, cfg, n_layers)
     # Zoo sites outside the scanned blocks: the embeddings-frontend
@@ -91,6 +101,16 @@ def _attn_sites(add, d, h, kvh, hd, count, prefix=""):
     add(prefix + "attn.wk", d, kvh * hd, count)
     add(prefix + "attn.wv", d, kvh * hd, count)
     add(prefix + "attn.wo", h * hd, d, count)
+
+
+def _mla_sites(add, cfg, count):
+    """Latent attention's projections as the naive forward runs them."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    add("attn.wq", d, h * (dn + dr), count)
+    add("attn.wkv_a", d, r + dr, count)
+    add("attn.wkv_b", r, h * (dn + dv), count)
+    add("attn.wo", h * dv, d, count)
 
 
 def _mlp_sites(add, cfg, count, prefix=""):

@@ -24,6 +24,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
     mlp_variant: str = "swiglu"    # swiglu | gelu (classic 4x MLP)
     tie_embeddings: bool = True    # False -> separate unembedding matrix
     attn_impl: str = "blockwise"   # blockwise | full
@@ -33,10 +34,29 @@ class ModelConfig:
     # Pallas kernel, same math) | fused_sc (fused, SC-sampled QK^T —
     # needs per-token rng keys, see models/attention.py)
     paged_attn: str = "unfused"
+    # latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank > 0 selects it.
+    # Queries are n_heads x (qk_nope_head_dim + qk_rope_head_dim); keys and
+    # values are decompressed from one kv_lora_rank-wide latent plus one
+    # rope part shared by every head, which is all the cache holds.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense SwiGLU layers (width d_ff) before the MoE stack
+    first_k_dense: int = 0
     # MoE
     n_experts: int = 0
     top_k: int = 0
-    shared_expert: bool = False
+    moe_d_ff: int = 0              # expert width; 0 -> d_ff
+    n_shared_experts: int = 0      # one SwiGLU of width n x expert width
+    # softmax | noaux_tc (DeepSeek-V3: sigmoid scores, a selection-only
+    # bias); the top-k gates are renormalized, then scaled
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # dropless: every (token, expert) pair is computed, sorted by expert
+    # through one grouped matmul (exact) or capacity = tokens (SC); else
+    # tokens past capacity_factor are dropped
+    moe_dropless: bool = False
     capacity_factor: float = 1.25
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -83,6 +103,14 @@ class ModelConfig:
         return self.head_dim or self.d_model // max(self.n_heads, 1)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
     def d_inner(self) -> int:           # SSM inner width
         return self.ssm_expand * self.d_model
 
@@ -116,7 +144,7 @@ SHAPES = {
 }
 
 ARCH_IDS = [
-    "musicgen-large", "moonshot-v1-16b-a3b", "llama4-maverick-400b-a17b",
+    "musicgen-large", "moonlight-16b-a3b", "llama4-maverick-400b-a17b",
     "chameleon-34b", "starcoder2-15b", "qwen2-0.5b", "qwen3-14b", "yi-6b",
     "zamba2-7b", "mamba2-370m", "paper-sc",
 ]

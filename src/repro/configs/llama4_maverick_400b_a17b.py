@@ -11,7 +11,7 @@ from repro.configs.base import ModelConfig
 CONFIG = ModelConfig(
     name="llama4-maverick-400b-a17b", family="moe", n_layers=48,
     d_model=5120, n_heads=40, n_kv_heads=8, d_ff=8192, vocab=202048,
-    n_experts=128, top_k=1, shared_expert=True, tie_embeddings=False)
+    n_experts=128, top_k=1, n_shared_experts=1, tie_embeddings=False)
 
 SMOKE = CONFIG.replace(
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=96, vocab=256,

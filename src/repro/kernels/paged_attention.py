@@ -24,6 +24,11 @@ per layer, flash-attention style:
   agree to float tolerance and greedy-decoded tokens are identical
   (``tests/test_paged_attention.py``).
 
+:func:`mla_paged_attention` runs latent attention's absorbed form on the
+same grid: one latent pool row per position serves every head (kvh = 1,
+a query group of all heads), its lanes are the keys and its first
+``kv_lora_rank`` lanes the values, and the scale is passed in.
+
 The SC variant (:func:`paged_attention_fused_sc`) replaces the exact
 QK^T with the paper's stochastic MUL: operands quantize onto the DTC
 grid in-kernel (``kernels/sc_fused.py:encode_fx16``), Bernoulli cells
@@ -156,6 +161,49 @@ def _paged_attn_kernel(
     logits = _mask(logits, len_ref[pl.program_id(0)], j=j, sc=sc,
                    block_size=block_size, block_q=block_q)
     _online_softmax_step(logits, v_blk, m_ref, d_ref, a_ref)
+
+    @pl.when(j == nb - 1)
+    def _():
+        _emit(o_ref, d_ref, a_ref)
+
+
+def _mla_attn_kernel(
+    bt_ref,
+    len_ref,
+    q_ref,
+    kv_ref,
+    o_ref,
+    m_ref,
+    d_ref,
+    a_ref,
+    *,
+    sc: int,
+    block_size: int,
+    nb: int,
+    block_q: int,
+    scale: float,
+    dv: int,
+):
+    """Absorbed-MLA step: one latent page is both the keys (all its
+    lanes) and the values (its first ``dv`` lanes)."""
+    del bt_ref  # consumed by the page index map
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        _init_carry(m_ref, d_ref, a_ref)
+
+    # scores on the operands' own dtype: bfloat16 products are exact in
+    # float32, so this equals an upcast at HIGHEST in one MXU pass
+    kv_blk = kv_ref[0, 0]  # (bs, dk)
+    exact = _HIGHEST if kv_blk.dtype == jnp.float32 else None
+    logits = jax.lax.dot_general(
+        q_ref[0, 0], kv_blk, (((1,), (1,)), ((), ())), precision=exact,
+        preferred_element_type=jnp.float32) * scale
+    logits = _mask(logits, len_ref[pl.program_id(0)], j=j, sc=sc,
+                   block_size=block_size, block_q=block_q)
+    _online_softmax_step(logits, kv_blk[:, :dv].astype(jnp.float32), m_ref,
+                         d_ref, a_ref)
 
     @pl.when(j == nb - 1)
     def _():
@@ -298,24 +346,27 @@ def _rows_unlayout(out, *, sc: int, h: int):
     return out.reshape(b, sc, h, hd)
 
 
-def _launch(kernel, *, name, q_rows, block_table, lengths, extra, k_pages,
-            v_pages, block_q):
+def _launch(kernel, *, name, q_rows, block_table, lengths, extra, pools,
+            block_q, out_width=None):
     """One fused-attention ``pallas_call`` over grid (b, kv, q tiles, nb),
     named ``name`` in the compiled program and the profiler's trace.
 
     q_rows: (b, kvh, rows_p, hd) kernel rows; extra: per-row operands of
-    shape (b, rows_p, w) blocked alongside the query tile.  The block
-    table (flattened) and lengths are scalar-prefetched; each K/V step
-    DMAs the (block_size, hd) slice of the page the table names.
+    shape (b, rows_p, w) blocked alongside the query tile; pools: the
+    (P, kvh, bs, w) page pools the kernel reads, K then V (one pool where
+    values are lanes of the key page).  The block table (flattened) and
+    lengths are scalar-prefetched; each pool's step DMAs the
+    (block_size, w) slice of the page the table names.  The output and
+    accumulator are ``out_width`` wide (default: the query width).
     """
     b, kvh, rows_p, hd = q_rows.shape
-    _, _, bs, _ = k_pages.shape
+    bs = pools[0].shape[2]
     nb = block_table.shape[1]
+    dv = out_width or hd
 
     def page(i, h_, qi, j, bt, ln):
         return (bt[i * nb + j], h_, 0, 0)
 
-    kv_spec = pl.BlockSpec((1, 1, bs, hd), page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, rows_p // block_q, nb),
@@ -325,28 +376,28 @@ def _launch(kernel, *, name, q_rows, block_table, lengths, extra, k_pages,
             *[pl.BlockSpec((1, block_q, e.shape[-1]),
                            lambda i, h_, qi, j, bt, ln: (i, qi, 0))
               for e in extra],
-            kv_spec,
-            kv_spec,
+            *[pl.BlockSpec((1, 1, bs, pool.shape[-1]), page)
+              for pool in pools],
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
                                lambda i, h_, qi, j, bt, ln: (i, h_, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows_p, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows_p, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=kernels.interpret_mode(),
         name=name,
     )(block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
-      q_rows, *extra, k_pages, v_pages)
+      q_rows, *extra, *pools)
 
 
 def _row_tile(block_q: int) -> int:
@@ -389,7 +440,41 @@ def paged_attention_fused(
     )
     out = _launch(kernel, name="paged_attention", q_rows=qr,
                   block_table=block_table, lengths=lengths, extra=(),
-                  k_pages=k_pages, v_pages=v_pages, block_q=block_q)
+                  pools=(k_pages, v_pages), block_q=block_q)
+    return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
+
+
+def mla_paged_attention(q, pool, block_table, lengths, *, dv: int,
+                        scale: float, block_q: int = 0):
+    """Absorbed latent attention (MLA) over a latent page pool.
+
+    q: (b, sc, h, dk) absorbed queries, ``[q_nope·W_UK, rope(q_pe)]``;
+    pool: (P, 1, bs, dk) latent pages, ``[c, rope(k_pe)]`` once per
+    position for every head; block_table, lengths: as
+    :func:`paged_attention_fused`.  An MQA of ``h`` query heads over one
+    latent "KV head": scores are ``q·page`` times ``scale`` (the
+    decompressed head's 1/sqrt(qk width), not 1/sqrt(dk)), values the
+    first ``dv`` lanes of the same page.  Returns the latent outputs
+    (b, sc, h, dv), which the caller takes up through W_UV.  Same grid,
+    page walk and masking as the GQA kernel (``_launch``).
+    """
+    b, sc, h, dk = q.shape
+    bs = pool.shape[2]
+    nb = block_table.shape[1]
+    rows = h * sc
+    if block_q <= 0:
+        block_q = autotune.get_attn_tile(rows, bs, dk, 0).block_q
+    block_q = _row_tile(block_q)
+    qr = _rows_layout(q, 1)
+    pad = (-rows) % block_q
+    if pad:
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    kernel = functools.partial(
+        _mla_attn_kernel, sc=sc, block_size=bs, nb=nb, block_q=block_q,
+        scale=float(scale), dv=dv)
+    out = _launch(kernel, name="mla_paged_attention", q_rows=qr,
+                  block_table=block_table, lengths=lengths, extra=(),
+                  pools=(pool,), block_q=block_q, out_width=dv)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 
@@ -454,7 +539,7 @@ def paged_attention_fused_sc(
     )
     out = _launch(kernel, name="paged_attention_sc", q_rows=qr,
                   block_table=block_table, lengths=lengths, extra=(rowk,),
-                  k_pages=k_pages, v_pages=v_pages, block_q=block_q)
+                  pools=(k_pages, v_pages), block_q=block_q)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 

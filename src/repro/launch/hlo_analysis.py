@@ -496,13 +496,14 @@ def param_count(cfg) -> int:
 
 
 def active_param_count(cfg) -> int:
-    """Active params per token (MoE: top_k of the expert stack + the rest)."""
+    """Active params per token (MoE: top_k of the routed expert stack +
+    the rest, shared experts and leading dense layers included)."""
     import math
     total = param_count(cfg)
     if cfg.family != "moe" or cfg.n_experts == 0:
         return total
     expert = sum(
         math.prod(s.shape) for keys, s in _spec_leaves_with_paths(cfg)
-        if "ffn" in keys and ("wi" in keys or "wo" in keys))
+        if keys[:2] == ["blocks", "ffn"] and keys[2:] in (["wi"], ["wo"]))
     active_expert = expert * cfg.top_k / cfg.n_experts
     return int(total - expert + active_expert)

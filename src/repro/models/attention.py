@@ -27,6 +27,8 @@ NEG_INF = -1e30
 
 
 def attn_specs(cfg):
+    if cfg.mla:
+        return mla_specs(cfg)
     d, h, kv, hd = (
         cfg.d_model,
         cfg.n_heads,
@@ -80,8 +82,8 @@ def _project_qkv(x, p, cfg, positions, key=None):
             b, s, kv, hd
         )
         if cfg.qk_norm:
-            q = layers.rms_norm(q, p["q_norm"])
-            k = layers.rms_norm(k, p["k_norm"])
+            q = layers.rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = layers.rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
         return q, k, v
@@ -214,15 +216,15 @@ def blockwise_attention(
     return out.astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, length):
+def decode_attention(q, k_cache, v_cache, length, scale=None):
     """One-token decode: q (b,1,h,d) against cache (b,L,kv,d); mask > length.
     The single-token special case of :func:`chunk_decode_attention` (the
     query sits at position ``length - 1``, i.e. a chunk of one at fill
     ``length - 1``)."""
-    return chunk_decode_attention(q, k_cache, v_cache, length - 1)
+    return chunk_decode_attention(q, k_cache, v_cache, length - 1, scale)
 
 
-def chunk_decode_attention(q, k_cache, v_cache, lengths):
+def chunk_decode_attention(q, k_cache, v_cache, lengths, scale=None):
     """Multi-token decode: a chunk of queries against a per-sequence cache.
 
     q: (b, sc, h, d) — chunk token i of row r sits at ABSOLUTE position
@@ -232,12 +234,14 @@ def chunk_decode_attention(q, k_cache, v_cache, lengths):
     ``length = lengths + 1`` — the single-token decode is the special case.
     This is the lookup the paged serve path drives after a
     ``paged_gather``; it is also what chunked prefill uses, which is why
-    one function serves both phases.
+    one function serves both phases.  ``scale`` defaults to 1/sqrt(d);
+    the values may be narrower than the keys (absorbed MLA).
     """
     b, sc, h, hd = q.shape
     kv = k_cache.shape[2]
     qg = _grouped(q, kv).astype(jnp.float32)  # (b,sc,kv,g,d)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     logits = (
         jnp.einsum("bskgd,btkd->bkgst", qg, k_cache.astype(jnp.float32))
         * scale
@@ -248,7 +252,7 @@ def chunk_decode_attention(q, k_cache, v_cache, lengths):
     logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", w, v_cache.astype(jnp.float32))
-    return out.reshape(b, sc, h, hd).astype(q.dtype)
+    return out.reshape(b, sc, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -305,11 +309,13 @@ def paged_copy_blocks(pages, src, dst):
     (``kv_cache.PagedKVCache.make_writable``) replaces a shared or
     hash-registered block in a sequence's table, the new block must carry
     the old block's K/V before the next scatter overwrites its tail.
-    pages: the pool dict with ``{"k", "v"}`` (layers, P, kv, bs, d)
-    arrays (extra non-paged leaves — e.g. a hybrid plan's ``"ssm"`` state
-    rows, which are slot- not block-indexed — pass through untouched);
-    src/dst: equal-length block-id vectors.  Pure indexed-copy — one
-    executable per distinct copy count (COW is rare and counts are tiny).
+    pages: the pool dict (``models/lm.py:init_paged_cache``) whose paged
+    leaves are (layers, P, ...) arrays — ``"k"``/``"v"``, or MLA's
+    latent ``"kv"``, and a leading dense stack's own under ``"dense"``
+    (a hybrid plan's ``"ssm"`` state rows, which are slot- not
+    block-indexed, pass through untouched); src/dst: equal-length
+    block-id vectors.  Pure indexed-copy — one executable per distinct
+    copy count (COW is rare and counts are tiny).
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
@@ -317,7 +323,13 @@ def paged_copy_blocks(pages, src, dst):
     def cp(pool):
         return pool.at[:, dst].set(pool[:, src])
 
-    return {**pages, "k": cp(pages["k"]), "v": cp(pages["v"])}
+    return {**pages, **jax.tree.map(cp, paged_leaves(pages))}
+
+
+def paged_leaves(pages) -> dict:
+    """The block-indexed part of a paged cache dict (everything but the
+    slot-indexed SSM state rows)."""
+    return {k: v for k, v in pages.items() if k != "ssm"}
 
 
 def paged_attention_block(
@@ -326,8 +338,7 @@ def paged_attention_block(
     cfg,
     positions,
     key,
-    k_pages,
-    v_pages,
+    pages,
     block_table,
     lengths,
     n_valid,
@@ -341,19 +352,23 @@ def paged_attention_block(
     gather + :func:`chunk_decode_attention`), ``"fused"`` (one Pallas
     kernel, same math to float tolerance), or ``"fused_sc"`` (fused with
     the SC-sampled QK^T; needs per-token keys and draws them under salt
-    29, disjoint from the dense-layer salts).  Returns
-    (out, new_k_pages, new_v_pages).
+    29, disjoint from the dense-layer salts).  ``pages`` is this layer's
+    ``{"k", "v"}`` pools (MLA: ``{"kv"}``, :func:`mla_paged_block`).
+    Returns (out, new_pages).
     """
+    if cfg.mla:
+        return mla_paged_block(x, p, cfg, positions, key, pages,
+                               block_table, lengths, n_valid)
     q, k, v = _project_qkv(x, p, cfg, positions, key)
     with jax.named_scope("attn"):
         out, k_pages, v_pages = _paged_lookup(
-            q, k, v, cfg, key, k_pages, v_pages, block_table, lengths,
+            q, k, v, cfg, key, pages["k"], pages["v"], block_table, lengths,
             n_valid)
     b, s, _, _ = out.shape
     okey = layers.fold_keys(key, 7)
     with jax.named_scope("attn_out"):
         out = layers.dense(out.reshape(b, s, -1), p["wo"], cfg, okey)
-    return out, k_pages, v_pages
+    return out, {"k": k_pages, "v": v_pages}
 
 
 def _paged_lookup(q, k, v, cfg, key, k_pages, v_pages, block_table, lengths,
@@ -409,8 +424,12 @@ def attention_block(
 
     Training/prefill: cache is None -> causal attention over the sequence
     (returns the full K/V so prefill can build the cache).
-    Decode: cache = (k, v) ring buffers; x is (b, 1, d).
+    Decode: cache = {"k", "v"} ring buffers (MLA: {"kv"}, the latent);
+    x is (b, 1, d).  The new cache comes back in the same form.
     """
+    if cfg.mla:
+        return mla_attention_block(x, p, cfg, positions, key, cache=cache,
+                                   cache_length=cache_length)
     cst = constrain or (lambda v_, *a: v_)
     q, k, v = _project_qkv(x, p, cfg, positions, key)
     # Layout choice per arch x mesh: TP over heads when the head count
@@ -449,7 +468,7 @@ def _contiguous_lookup(q, k, v, cfg, cache, cache_length, positions,
     """Attention over the ring-buffer cache (decode) or the sequence
     itself (training/prefill).  Returns (out, new_cache)."""
     if cache is not None:
-        kc, vc = cache
+        kc, vc = cache["k"], cache["v"]
         pos = positions[:, 0]  # (b,) write index
         kc = jax.vmap(
             lambda c, upd, i: jax.lax.dynamic_update_slice(
@@ -462,7 +481,7 @@ def _contiguous_lookup(q, k, v, cfg, cache, cache_length, positions,
             )
         )(vc, v, pos)
         out = decode_attention(q, kc, vc, cache_length)
-        new_cache = (kc, vc)
+        new_cache = {"k": kc, "v": vc}
     else:
         if cfg.attn_impl == "full":
             out = full_attention(q, k, v, causal=True)
@@ -476,5 +495,176 @@ def _contiguous_lookup(q, k, v, cfg, cache, cache_length, positions,
                 # CP: q already sharded over `model` -> single q block
                 q_chunk=None if heads_tp else q.shape[1],
             )
-        new_cache = (k, v)
+        new_cache = {"k": k, "v": v}
     return out, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+#
+#   q = Wq·x: h heads of (nope + rope);  [c, k_pe] = Wkv_a·x, c normed
+#   rope on q_pe and k_pe only (k_pe: one head, shared by all)
+#   [k_nope, v] = Wkv_b·c per head;  scores [q_nope, q_pe]·[k_nope, k_pe]
+#   scaled by 1/sqrt(nope + rope);  o = Wo·(softmax·v)
+#
+# The naive form (training, forward, prefill) decompresses K and V per
+# head.  The absorbed form (every decode and paged step) never does:
+# q_lat = q_nope·W_UK per head, scores q_lat·c + q_pe·k_pe, o_lat =
+# Σ p·c, v = o_lat·W_UV.  So the cache holds only [c, k_pe] — one
+# (kv_lora_rank + rope)-wide row per position, shared by every head.
+
+
+def mla_specs(cfg):
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((d, h * (dn + dr)), ("embed", "heads"), "scaled"),
+        "wkv_a": ParamSpec((d, r + dr), ("embed", None), "scaled"),
+        "kv_norm": ParamSpec((r,), (None,), "ones"),
+        # per head: [k_nope | v] columns (the published kv_b_proj layout)
+        "wkv_b": ParamSpec((r, h * (dn + dv)), (None, "heads"), "scaled"),
+        "wo": ParamSpec((h * dv, d), ("heads", "embed"), "scaled"),
+    }
+
+
+def latent_width(cfg) -> int:
+    """Values per cached position per layer: [c, k_pe]."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def mla_scale(cfg) -> float:
+    return 1.0 / float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+
+
+def _mla_project(x, p, cfg, positions, key):
+    """(q_nope, rope(q_pe), latent [c, rope(k_pe)] (b, s, 1, r + rope))."""
+    b, s, _ = x.shape
+    h, dn = cfg.n_heads, cfg.qk_nope_head_dim
+    with jax.named_scope("mla_q"):
+        q = layers.dense(x, p["wq"], cfg, layers.site_key(key, "mla_q"),
+                         site="mla_q").reshape(b, s, h, -1)
+        q_pe = layers.apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    with jax.named_scope("mla_kv_a"):
+        ckv = layers.dense(x, p["wkv_a"], cfg,
+                           layers.site_key(key, "mla_kv_a"), site="mla_kv_a")
+        c = layers.rms_norm(ckv[..., :cfg.kv_lora_rank], p["kv_norm"],
+                            cfg.rms_norm_eps)
+        k_pe = layers.apply_rope(ckv[..., None, cfg.kv_lora_rank:],
+                                 positions, cfg.rope_theta)
+        latent = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
+    return q[..., :dn], q_pe, latent
+
+
+def _kv_b(p, cfg):
+    """W_kv_b as (r, h, nope + v): [W_UK | W_UV] per head."""
+    return p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+
+
+def _head_dense(x, w, cfg, key, site):
+    """Per-head weight matmul: x (..., h, K) @ w (K, h, N) -> (..., h, N),
+    through the substrate with each head's key folded from ``site``."""
+    if cfg.sc_backend == "exact":
+        return jnp.einsum("...hk,khn->...hn", x, w.astype(x.dtype))
+    return jnp.stack(
+        [layers.dense(x[..., i, :], w[:, i], cfg,
+                      layers.site_key(key, site, i), site=site)
+         for i in range(w.shape[1])], axis=-2)
+
+
+def _absorbed_q(q_nope, q_pe, p, cfg, key):
+    """[q_nope·W_UK, q_pe] (b, s, h, r + rope): queries against latents."""
+    with jax.named_scope("mla_q"):
+        w_uk = _kv_b(p, cfg)[..., :cfg.qk_nope_head_dim]
+        q_lat = _head_dense(q_nope, jnp.transpose(w_uk, (2, 1, 0)), cfg,
+                            key, "mla_uk")
+        return jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+
+
+def _mla_out(o_lat, p, cfg, key):
+    """Latent outputs (b, s, h, r) -> W_UV per head -> Wo."""
+    b, s = o_lat.shape[:2]
+    with jax.named_scope("mla_v_up"):
+        w_uv = _kv_b(p, cfg)[..., cfg.qk_nope_head_dim:]
+        v = _head_dense(o_lat, w_uv, cfg, key, "mla_uv")
+    with jax.named_scope("attn_out"):
+        return layers.dense(v.reshape(b, s, -1), p["wo"], cfg,
+                            layers.fold_keys(key, 7))
+
+
+def mla_attention_block(x, p, cfg, positions, key=None, *, cache=None,
+                        cache_length=None):
+    """MLA sub-block over the sequence (naive form; cache None) or over a
+    contiguous latent cache {"kv": (b, L, 1, r + rope)} (absorbed form,
+    one new position per row at ``positions[:, 0]``).  Returns (out,
+    {"kv": ...}): the sequence's own latents, or the updated cache."""
+    q_nope, q_pe, latent = _mla_project(x, p, cfg, positions, key)
+    if cache is None:
+        b, s, h = *x.shape[:2], cfg.n_heads
+        dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        with jax.named_scope("mla_kv_b"):
+            kv = layers.dense(latent[:, :, 0, :cfg.kv_lora_rank], p["wkv_b"],
+                              cfg, layers.site_key(key, "mla_kv_b"),
+                              site="mla_kv_b").reshape(b, s, h, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(latent[..., cfg.kv_lora_rank:],
+                                            (b, s, h, cfg.qk_rope_head_dim)
+                                            ).astype(kv.dtype)], axis=-1)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        # values zero-padded to the query width (exact: the padded lanes
+        # carry nothing), then cut back
+        v = jnp.pad(kv[..., dn:], ((0, 0),) * 3 + ((0, q.shape[-1] - dv),))
+        with jax.named_scope("attn"):
+            if cfg.attn_impl == "full":
+                o = full_attention(q, k, v, causal=True)
+            else:
+                o = blockwise_attention(q, k, v, causal=True,
+                                        chunk=cfg.attn_chunk)
+        with jax.named_scope("attn_out"):
+            out = layers.dense(o[..., :dv].reshape(b, s, h * dv), p["wo"],
+                               cfg, layers.fold_keys(key, 7))
+        return out, {"kv": latent}
+    kc = jax.vmap(lambda c, upd, i: jax.lax.dynamic_update_slice(
+        c, upd.astype(c.dtype), (i, 0, 0)))(cache["kv"], latent,
+                                            positions[:, 0])
+    q = _absorbed_q(q_nope, q_pe, p, cfg, key)
+    with jax.named_scope("attn"):
+        o_lat = decode_attention(q, kc, kc[..., :cfg.kv_lora_rank],
+                                 cache_length, mla_scale(cfg))
+    return _mla_out(o_lat, p, cfg, key), {"kv": kc}
+
+
+def mla_paged_block(x, p, cfg, positions, key, pages, block_table, lengths,
+                    n_valid):
+    """MLA over the paged latent pool {"kv": (P, 1, bs, r + rope)}: the
+    chunk's latents scatter in once, then the absorbed lookup —
+    ``mla_paged_attention`` (``paged_attn="fused"``) or gather +
+    :func:`chunk_decode_attention` (``"unfused"``, the reference)."""
+    q_nope, q_pe, latent = _mla_project(x, p, cfg, positions, key)
+    q = _absorbed_q(q_nope, q_pe, p, cfg, key)
+    r = cfg.kv_lora_rank
+    mode = getattr(cfg, "paged_attn", "unfused")
+    with jax.named_scope("attn"):
+        pool = paged_scatter(pages["kv"], block_table, latent, lengths,
+                             n_valid)
+        if mode == "fused":
+            o_lat = paged_attention.mla_paged_attention(
+                q, pool, block_table, lengths, dv=r, scale=mla_scale(cfg))
+        elif mode == "unfused":
+            kc = paged_gather(pool, block_table)
+            o_lat = chunk_decode_attention(q, kc, kc[..., :r], lengths,
+                                           mla_scale(cfg))
+        else:
+            raise ValueError(
+                f"paged_attn={mode!r} has no latent-attention (MLA) form; "
+                "use 'fused' or 'unfused'")
+    return _mla_out(o_lat, p, cfg, key), {"kv": pool}
+
+
+def cache_leaves(cfg) -> dict:
+    """Per-position, per-layer shape of each cache leaf: GQA K and V
+    ``(kv_heads, head_dim)`` each; MLA one latent ``(1, r + rope)``."""
+    if cfg.mla:
+        return {"kv": (1, latent_width(cfg))}
+    kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    return {"k": (kvh, hd), "v": (kvh, hd)}

@@ -57,6 +57,11 @@ SITES: dict = {
     "ssm_wdt": 67,
     "unembed": 71,
     "frontend_proj": 73,
+    "mla_q": 79,
+    "mla_kv_a": 83,
+    "mla_kv_b": 89,
+    "mla_uk": 97,
+    "mla_uv": 101,
 }
 
 
@@ -105,7 +110,8 @@ def _dense_rows(keys, x, w, sc_cfg):
     return yf.reshape(*lead, w.shape[-1]).astype(x.dtype)
 
 
-def dense(x, w, cfg, key=None, bias=None, site: str = "dense"):
+def dense(x, w, cfg, key=None, bias=None, site: str = "dense",
+          precision=None):
     """x @ w with the configured multiplication substrate.
 
     x: (..., K); w: (K, N) (or pre-reshaped 2-D view of a fused projection).
@@ -122,10 +128,11 @@ def dense(x, w, cfg, key=None, bias=None, site: str = "dense"):
     over the data axes, contraction over model with a psum merge) — the
     scope is consulted at trace time, so callers scale across devices with
     no signature changes (per-row keys are a single-mesh-slice feature and
-    take precedence when both apply).
+    take precedence when both apply).  ``precision`` reaches the exact
+    substrate's ``jnp.dot`` (the float32 router asks for ``HIGHEST``).
     """
     if cfg.sc_backend == "exact":
-        y = jnp.dot(x, w.astype(x.dtype))
+        y = jnp.dot(x, w.astype(x.dtype), precision=precision)
     elif key is None:
         raise ValueError(
             f"layers.dense at site {site!r}: sc_backend="
@@ -160,8 +167,9 @@ def dense(x, w, cfg, key=None, bias=None, site: str = "dense"):
 # ----------------------------- MLP (SwiGLU) --------------------------------
 
 
-def mlp_specs(cfg):
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_specs(cfg, width: int = 0):
+    """SwiGLU (or GELU) MLP of hidden ``width`` (0 -> ``cfg.d_ff``)."""
+    d, f = cfg.d_model, width or cfg.d_ff
     wi_cols = 2 * f if cfg.mlp_variant == "swiglu" else f
     return {
         "wi": ParamSpec((d, wi_cols), ("embed", "mlp"), "scaled"),

@@ -3,7 +3,14 @@
 One code path serves all ten assigned architectures:
 
   dense / audio / vlm : N × (RMSNorm → GQA attn → RMSNorm → SwiGLU MLP)
-  moe                 : N × (RMSNorm → GQA attn → RMSNorm → MoE FFN)
+  moe                 : K × dense block, then (N - K) × (RMSNorm → attn
+                        → RMSNorm → MoE FFN); ``first_k_dense`` = K leading
+                        dense layers (width ``d_ff``) hold their own
+                        parameter subtree (``dense_blocks``) and cache
+                        (``"dense"``) and run before the scanned MoE stack
+                        (DeepSeek-V3 / Moonlight: K = 1).  Attention is GQA,
+                        or latent attention (MLA) when ``kv_lora_rank`` > 0,
+                        whose cache holds one latent row per position
   ssm                 : N × (RMSNorm → Mamba2/SSD block)
   hybrid (zamba2)     : groups of ``attn_every`` Mamba2 layers followed by
                         ONE weight-shared (attn + MLP) block; the scan runs
@@ -14,7 +21,7 @@ One code path serves all ten assigned architectures:
 Layers are stacked and scanned (``jax.lax.scan`` + per-layer remat), so
 compile time and HLO size are O(1) in depth — a 48-layer 400B config lowers
 as fast as a 2-layer smoke config. The per-layer PRNG for the SC engine is
-folded from the layer index inside the scan.
+folded from the absolute layer index (leading dense layers first).
 """
 
 from __future__ import annotations
@@ -43,6 +50,12 @@ def block_specs(cfg):
             "ln2": _norm_spec(cfg), "ffn": ffn}
 
 
+def dense_block_specs(cfg):
+    """A leading dense layer: the backbone's attention, a SwiGLU of d_ff."""
+    return {"ln1": _norm_spec(cfg), "attn": attention.attn_specs(cfg),
+            "ln2": _norm_spec(cfg), "ffn": layers.mlp_specs(cfg)}
+
+
 def shared_block_specs(cfg):
     """zamba2's weight-shared transformer block (MHA + MLP)."""
     return {"ln1": _norm_spec(cfg), "attn": attention.attn_specs(cfg),
@@ -57,10 +70,11 @@ def stack_specs(specs, n: int):
 
 def n_backbone_layers(cfg) -> int:
     """Scanned backbone depth (hybrid: ssm layers only; `n_layers` counts
-    ssm layers + shared invocations)."""
+    ssm layers + shared invocations; moe: the layers after the
+    ``first_k_dense`` leading dense ones)."""
     if cfg.family == "hybrid":
         return cfg.n_layers * cfg.attn_every // (cfg.attn_every + 1)
-    return cfg.n_layers
+    return cfg.n_layers - cfg.first_k_dense
 
 
 def n_shared_invocations(cfg) -> int:
@@ -75,6 +89,9 @@ def lm_param_specs(cfg):
         "blocks": stack_specs(block_specs(cfg), n_backbone_layers(cfg)),
         "final_norm": _norm_spec(cfg),
     }
+    if cfg.first_k_dense:
+        sp["dense_blocks"] = stack_specs(dense_block_specs(cfg),
+                                         cfg.first_k_dense)
     if not cfg.tie_embeddings:
         sp["unembed"] = ParamSpec((cfg.d_model, cfg.vocab),
                                   ("embed", "vocab"), "scaled")
@@ -108,37 +125,66 @@ def _group(tree, ninv: int, per: int):
 # --------------------------------------------------------------------------
 
 
+def _norm(x, w, cfg):
+    return layers.rms_norm(x, w, cfg.rms_norm_eps)
+
+
+def _ffn(x, p, cfg, key, cst=None):
+    """The block's FFN: MoE where the block holds a router, else MLP."""
+    if "router" in p:
+        return moe.moe_ffn(x, p, cfg, key, constrain=cst)
+    return layers.mlp(x, p, cfg, key, constrain=cst)
+
+
+def _run_layers(body, x, params, cfg, cache=None):
+    """The attention families' layer loop: the ``first_k_dense`` leading
+    dense layers one by one, then the scanned backbone.  ``body((x,
+    idx), xs) -> ((x, idx + 1), y)`` sees each layer's params (and, with
+    ``cache``, that layer's cache leaves as ``(params, cache)``) at its
+    absolute index.  Returns (x, the backbone's stacked y with the dense
+    layers' under ``"dense"``)."""
+    dense = []
+    for i in range(cfg.first_k_dense):
+        xs = params["dense_blocks"]
+        if cache is not None:
+            xs = (xs, cache["dense"])
+        (x, _), y = body((x, i), jax.tree.map(lambda v: v[i], xs))
+        dense.append(y)
+    xs = params["blocks"]
+    if cache is not None:
+        xs = (xs, {k: v for k, v in cache.items() if k != "dense"})
+    (x, _), ys = jax.lax.scan(body, (x, cfg.first_k_dense), xs)
+    if dense and ys is not None:
+        ys["dense"] = jax.tree.map(lambda *vs: jnp.stack(vs), *dense)
+    return x, ys
+
+
 def _apply_block(x, p, cfg, positions, key, cache=None, cache_length=None,
                  cst=None):
     """One backbone block (pre-norm residual). Returns (x, new_cache)."""
     if cfg.family in ("ssm", "hybrid"):
-        h, new_cache = ssm.ssm_block(layers.rms_norm(x, p["ln1"]), p["ssm"],
+        h, new_cache = ssm.ssm_block(_norm(x, p["ln1"], cfg), p["ssm"],
                                      cfg, key, cache=cache, constrain=cst)
         return x + h, new_cache
     akey = None if key is None else jax.random.fold_in(key, 11)
     h, new_cache = attention.attention_block(
-        layers.rms_norm(x, p["ln1"]), p["attn"], cfg, positions, akey,
+        _norm(x, p["ln1"], cfg), p["attn"], cfg, positions, akey,
         cache=cache, cache_length=cache_length, constrain=cst)
     x = x + h
     fkey = None if key is None else jax.random.fold_in(key, 13)
-    if cfg.family == "moe":
-        h = moe.moe_ffn(layers.rms_norm(x, p["ln2"]), p["ffn"], cfg, fkey,
-                        constrain=cst)
-    else:
-        h = layers.mlp(layers.rms_norm(x, p["ln2"]), p["ffn"], cfg, fkey,
-                       constrain=cst)
-    return x + h, new_cache
+    return x + _ffn(_norm(x, p["ln2"], cfg), p["ffn"], cfg, fkey, cst), \
+        new_cache
 
 
 def _apply_shared(x, p, cfg, positions, key, cache=None, cache_length=None,
                   cst=None):
     akey = None if key is None else jax.random.fold_in(key, 17)
     h, new_cache = attention.attention_block(
-        layers.rms_norm(x, p["ln1"]), p["attn"], cfg, positions, akey,
+        _norm(x, p["ln1"], cfg), p["attn"], cfg, positions, akey,
         cache=cache, cache_length=cache_length, constrain=cst)
     x = x + h
     mkey = None if key is None else jax.random.fold_in(key, 19)
-    x = x + layers.mlp(layers.rms_norm(x, p["ln2"]), p["mlp"], cfg, mkey,
+    x = x + layers.mlp(_norm(x, p["ln2"], cfg), p["mlp"], cfg, mkey,
                        constrain=cst)
     return x, new_cache
 
@@ -198,10 +244,9 @@ def encode(params, inputs, cfg, *, rng=None, constrain=None,
             xc = cst(xc, "batch", "resid_seq", None)
             return (xc, idx + 1), None
 
-        (x, _), _ = jax.lax.scan(_maybe_remat(body, cfg), (x, 0),
-                                 params["blocks"])
+        x, _ = _run_layers(_maybe_remat(body, cfg), x, params, cfg)
 
-    return layers.rms_norm(x, params["final_norm"])
+    return _norm(x, params["final_norm"], cfg)
 
 
 def forward(params, inputs, cfg, *, rng=None, constrain=None,
@@ -276,8 +321,15 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None):
             cache["shared_v"] = jnp.zeros((ninv, batch, max_len, kvh, hd),
                                           dtype)
         return cache
-    return {"k": jnp.zeros((n, batch, max_len, kvh, hd), dtype),
-            "v": jnp.zeros((n, batch, max_len, kvh, hd), dtype)}
+
+    def leaves(layers_):
+        return {name: jnp.zeros((layers_, batch, max_len) + shape, dtype)
+                for name, shape in attention.cache_leaves(cfg).items()}
+
+    cache = leaves(n)
+    if cfg.first_k_dense:
+        cache["dense"] = leaves(cfg.first_k_dense)
+    return cache
 
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
@@ -289,7 +341,11 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
     blocks per layer, laid out (blocks, kv heads, block_size, head_dim)
     and addressed through per-sequence block tables
     (``serve/kv_cache.py`` owns the allocator; block 0 is the reserved
-    null block padding writes land in).
+    null block padding writes land in).  GQA pages K and V (``"k"``,
+    ``"v"``); latent attention pages one ``"kv"`` pool of (blocks, 1,
+    block_size, kv_lora_rank + rope): each position's [c, k_pe] once,
+    read as keys and values alike.  Leading dense layers keep their own
+    pools under ``"dense"``.
 
     SSM: state is O(1) per sequence — nothing to page.  The cache is one
     fixed-size state + conv-tail row PER BATCH ROW (``slots``), carried
@@ -317,8 +373,17 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None,
             pages["v"] = jnp.zeros((ninv, num_blocks, kvh, block_size, hd),
                                    dtype)
         return pages
-    return {"k": jnp.zeros((n, num_blocks, kvh, block_size, hd), dtype),
-            "v": jnp.zeros((n, num_blocks, kvh, block_size, hd), dtype)}
+
+    def pools(layers_):
+        return {name: jnp.zeros((layers_, num_blocks, heads, block_size,
+                                 width), dtype)
+                for name, (heads, width)
+                in attention.cache_leaves(cfg).items()}
+
+    pages = pools(n)
+    if cfg.first_k_dense:
+        pages["dense"] = pools(cfg.first_k_dense)
+    return pages
 
 
 def _reset_fresh_state(cache, lengths):
@@ -402,7 +467,7 @@ def decode_paged(params, pages, block_table, tokens, lengths, n_valid, cfg,
             xc, idx = carry
             lp, lc = scanned
             lkeys = layers.fold_keys(keys, idx)
-            h, nc = ssm.ssm_stream(layers.rms_norm(xc, lp["ln1"]),
+            h, nc = ssm.ssm_stream(_norm(xc, lp["ln1"], cfg),
                                    lp["ssm"], cfg, lkeys, lc, valid)
             return (xc + h, idx + 1), nc
 
@@ -423,20 +488,21 @@ def decode_paged(params, pages, block_table, tokens, lengths, n_valid, cfg,
                 lp = jax.tree.map(lambda v: v[j], gp)
                 lc = jax.tree.map(lambda v: v[j], gc)
                 lkeys = layers.fold_keys(keys, idx * per + j)
-                h, nc = ssm.ssm_stream(layers.rms_norm(xc, lp["ln1"]),
+                h, nc = ssm.ssm_stream(_norm(xc, lp["ln1"], cfg),
                                        lp["ssm"], cfg, lkeys, lc, valid)
                 xc = xc + h
                 new_ssm.append(nc)
             new_ssm = jax.tree.map(lambda *vs: jnp.stack(vs), *new_ssm)
             k2 = layers.fold_keys(keys, 10_000 + idx)
-            h, kp, vp = attention.paged_attention_block(
-                layers.rms_norm(xc, params["shared"]["ln1"]),
+            h, kv = attention.paged_attention_block(
+                _norm(xc, params["shared"]["ln1"], cfg),
                 params["shared"]["attn"], cfg, positions,
-                layers.fold_keys(k2, 17), kp, vp, block_table, lengths,
-                n_valid)
+                layers.fold_keys(k2, 17), {"k": kp, "v": vp}, block_table,
+                lengths, n_valid)
+            kp, vp = kv["k"], kv["v"]
             xc = xc + h
             xc = xc + layers.mlp(
-                layers.rms_norm(xc, params["shared"]["ln2"]),
+                _norm(xc, params["shared"]["ln2"], cfg),
                 params["shared"]["mlp"], cfg, layers.fold_keys(k2, 19))
             return (xc, idx + 1), (new_ssm, kp, vp)
 
@@ -449,27 +515,20 @@ def decode_paged(params, pages, block_table, tokens, lengths, n_valid, cfg,
     else:
         def body(carry, scanned):
             xc, idx = carry
-            lp, kp, vp = scanned
+            lp, lc = scanned
             lkeys = layers.fold_keys(keys, idx)
-            h, kp, vp = attention.paged_attention_block(
-                layers.rms_norm(xc, lp["ln1"]), lp["attn"], cfg, positions,
-                layers.fold_keys(lkeys, 11), kp, vp, block_table, lengths,
+            h, lc = attention.paged_attention_block(
+                _norm(xc, lp["ln1"], cfg), lp["attn"], cfg, positions,
+                layers.fold_keys(lkeys, 11), lc, block_table, lengths,
                 n_valid)
             xc = xc + h
-            fkey = layers.fold_keys(lkeys, 13)
-            if cfg.family == "moe":
-                h = moe.moe_ffn(layers.rms_norm(xc, lp["ln2"]), lp["ffn"],
-                                cfg, fkey)
-            else:
-                h = layers.mlp(layers.rms_norm(xc, lp["ln2"]), lp["ffn"],
-                               cfg, fkey)
-            return (xc + h, idx + 1), (kp, vp)
+            h = _ffn(_norm(xc, lp["ln2"], cfg), lp["ffn"], cfg,
+                     layers.fold_keys(lkeys, 13))
+            return (xc + h, idx + 1), lc
 
-        (x, _), (k_new, v_new) = jax.lax.scan(
-            body, (x, 0), (params["blocks"], pages["k"], pages["v"]))
-        new_pages = {"k": k_new, "v": v_new}
+        x, new_pages = _run_layers(body, x, params, cfg, pages)
 
-    x = layers.rms_norm(x, params["final_norm"])
+    x = _norm(x, params["final_norm"], cfg)
     if all_logits:
         return _logits(x, params, cfg, keys), new_pages
     last = jnp.maximum(n_valid - 1, 0)
@@ -518,10 +577,11 @@ def decode_step(params, cache, tokens, lengths, cfg, *, rng=None,
                 new_ssm = jax.tree.map(lambda *vs: jnp.stack(vs), *new_ssm)
                 k2 = (None if rng is None
                       else jax.random.fold_in(rng, 10_000 + idx))
-                xc, (kc2, vc2) = _apply_shared(
-                    xc, params["shared"], cfg, positions, k2, cache=(kc, vc),
-                    cache_length=new_lengths, cst=cst)
-                return (xc, idx + 1), (new_ssm, kc2, vc2)
+                xc, kv = _apply_shared(
+                    xc, params["shared"], cfg, positions, k2,
+                    cache={"k": kc, "v": vc}, cache_length=new_lengths,
+                    cst=cst)
+                return (xc, idx + 1), (new_ssm, kv["k"], kv["v"])
 
             (x, _), (new_ssm_g, k_new, v_new) = jax.lax.scan(
                 gbody, (x, 0),
@@ -547,18 +607,16 @@ def decode_step(params, cache, tokens, lengths, cfg, *, rng=None,
     else:
         def body(carry, scanned):
             xc, idx = carry
-            lp, kc, vc = scanned
+            lp, lc = scanned
             key = None if rng is None else jax.random.fold_in(rng, idx)
-            xc, (kc2, vc2) = _apply_block(xc, cstp(lp), cfg, positions, key,
-                                          cache=(kc, vc),
-                                          cache_length=new_lengths, cst=cst)
-            return (xc, idx + 1), (kc2, vc2)
+            xc, lc = _apply_block(xc, cstp(lp), cfg, positions, key,
+                                  cache=lc, cache_length=new_lengths,
+                                  cst=cst)
+            return (xc, idx + 1), lc
 
-        (x, _), (k_new, v_new) = jax.lax.scan(
-            body, (x, 0), (params["blocks"], cache["k"], cache["v"]))
-        new_cache = {"k": k_new, "v": v_new}
+        x, new_cache = _run_layers(body, x, params, cfg, cache)
 
-    x = layers.rms_norm(x, params["final_norm"])
+    x = _norm(x, params["final_norm"], cfg)
     logits = _logits(x[:, 0], params, cfg, rng)
     return cst(logits, "batch", "vocab"), new_cache
 
@@ -577,15 +635,12 @@ def prefill(params, inputs, cfg, max_len: int, *, rng=None, constrain=None,
     x = _embed_inputs(params, inputs, cfg, rng)
     b, s = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
 
     def pad_kv(kv):
-        k, v = kv
-        kp = jnp.zeros((b, max_len, kvh, hd), k.dtype)
-        vp = jnp.zeros((b, max_len, kvh, hd), v.dtype)
-        kp = jax.lax.dynamic_update_slice(kp, k, (0, 0, 0, 0))
-        vp = jax.lax.dynamic_update_slice(vp, v, (0, 0, 0, 0))
-        return kp, vp
+        """A layer's cache leaves (b, s, ...) -> (b, max_len, ...)."""
+        return jax.tree.map(
+            lambda v: jnp.pad(v, ((0, 0), (0, max_len - s))
+                              + ((0, 0),) * (v.ndim - 2)), kv)
 
     if cfg.family in ("ssm", "hybrid"):
         if cfg.family == "hybrid":
@@ -607,8 +662,8 @@ def prefill(params, inputs, cfg, max_len: int, *, rng=None, constrain=None,
                       else jax.random.fold_in(rng, 10_000 + idx))
                 xc, kv = _apply_shared(xc, params["shared"], cfg, positions,
                                        k2, cst=cst)
-                kp, vp = pad_kv(kv)
-                return (xc, idx + 1), (ssm_c, kp, vp)
+                kv = pad_kv(kv)
+                return (xc, idx + 1), (ssm_c, kv["k"], kv["v"])
 
             (x, _), (ssm_g, kp, vp) = jax.lax.scan(
                 _maybe_remat(gbody, cfg), (x, 0), grouped)
@@ -632,14 +687,11 @@ def prefill(params, inputs, cfg, max_len: int, *, rng=None, constrain=None,
             xc, idx = carry
             key = None if rng is None else jax.random.fold_in(rng, idx)
             xc, kv = _apply_block(xc, cstp(lp), cfg, positions, key, cst=cst)
-            kp, vp = pad_kv(kv)
-            return (xc, idx + 1), (kp, vp)
+            return (xc, idx + 1), pad_kv(kv)
 
-        (x, _), (k_all, v_all) = jax.lax.scan(_maybe_remat(body, cfg), (x, 0),
-                                              params["blocks"])
-        cache = {"k": k_all, "v": v_all}
+        x, cache = _run_layers(_maybe_remat(body, cfg), x, params, cfg)
 
-    x = layers.rms_norm(x, params["final_norm"])
+    x = _norm(x, params["final_norm"], cfg)
     logits = _logits(x[:, -1], params, cfg, rng)
     lengths = jnp.full((b,), s, jnp.int32)
     return cst(logits, "batch", "vocab"), cache, lengths

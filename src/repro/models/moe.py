@@ -1,19 +1,28 @@
-"""Mixture-of-Experts FFN with capacity-bounded sort dispatch (EP-ready).
+"""Mixture-of-Experts FFN: router, dropless grouped dispatch, capacity
+dispatch (EP-ready), shared experts.
 
-Design notes (these choices are what make the 400B config lower cleanly):
+Routers (:func:`route`): softmax top-k, or DeepSeek-V3's sigmoid scores
+with a selection-only bias; gates renormalized, then scaled.
 
-* Routing, sort, and capacity bookkeeping happen **per batch row** (axis 0
-  stays the data-sharded batch), so the sort is a local operation per shard —
-  no global argsort collectives appear in the HLO.
-* Dispatch is gather-based (Megablocks-style capacity buffers), NOT the
+Dispatch, by what the config states:
+
+* **dropless** (``moe_dropless``, exact substrate): the (token, expert)
+  pairs of the whole step are sorted by expert and each projection is
+  ONE grouped matmul over them (``kernels/moe_gmm.py``, named
+  ``moe_gmm``).  No token is dropped at any batch or chunk width.
+* **capacity** (the SC substrates, and every config that is not
+  dropless): routing, sort and capacity bookkeeping happen **per batch
+  row** (axis 0 stays the data-sharded batch), so the sort is a local
+  operation per shard — no global argsort collectives appear in the HLO.
+  Dispatch is gather-based (Megablocks-style capacity buffers), NOT the
   GShard one-hot-einsum formulation: the (tokens × experts × capacity)
-  dispatch tensor is never materialized and no fake dispatch-FLOPs pollute
-  the roofline (MODEL_FLOPS/HLO_FLOPs stays honest).
-* Expert weights carry the "experts" logical axis -> TP/EP over the model
-  mesh axis; the capacity buffer gets a sharding constraint on its expert
-  axis, which XLA resolves into the canonical MoE all-to-all.
-* Top-k gates are renormalized; overflow beyond the capacity factor drops
-  tokens (standard capacity semantics; cf defaults to 1.25).
+  dispatch tensor is never materialized.  Expert weights carry the
+  "experts" logical axis -> TP/EP over the model mesh axis; the capacity
+  buffer gets a sharding constraint on its expert axis, which XLA
+  resolves into the canonical MoE all-to-all.  Overflow beyond the
+  capacity factor drops tokens (standard capacity semantics; cf defaults
+  to 1.25); a dropless config sizes capacity to the row, so nothing
+  overflows there either.
 """
 
 from __future__ import annotations
@@ -21,12 +30,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import moe_gmm
 from repro.models import layers
 from repro.models.params import ParamSpec
 
 
 def moe_specs(cfg):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
     sp = {
         "router": ParamSpec((d, e), ("embed", None), "scaled"),
         # EP owns the model axis via "experts"; the expert-internal FFN dim
@@ -37,15 +47,49 @@ def moe_specs(cfg):
         "wo": ParamSpec((e, f, d), ("experts", "expert_mlp", "embed"),
                         "scaled"),
     }
-    if cfg.shared_expert:
-        sp["shared"] = layers.mlp_specs(cfg)
+    if cfg.router == "noaux_tc":
+        sp["router_bias"] = ParamSpec((e,), (None,), "zeros", jnp.float32)
+    if cfg.n_shared_experts:
+        sp["shared"] = layers.mlp_specs(cfg, cfg.n_shared_experts * f)
     return sp
 
 
 def capacity(cfg, tokens_per_row: int) -> int:
-    c = int(tokens_per_row * cfg.top_k * cfg.capacity_factor
-            / max(cfg.n_experts, 1))
+    if cfg.moe_dropless:
+        # a token picks top_k DISTINCT experts, so no expert receives more
+        # than one slot per token of its row: nothing can overflow
+        c = tokens_per_row
+    else:
+        c = int(tokens_per_row * cfg.top_k * cfg.capacity_factor
+                / max(cfg.n_experts, 1))
     return max(8, -(-c // 8) * 8)          # round up to a multiple of 8
+
+
+def route(x, p, cfg, key=None):
+    """Router: x (b, s, d) -> (gates (b, s, k) float32, experts (b, s, k)).
+
+    ``softmax``: top-k of the softmax over experts.  ``noaux_tc``
+    (DeepSeek-V3, one group): scores s = sigmoid(x·Wr) in float32; the
+    experts are the top-k of s + b, where ``router_bias`` b steers
+    selection only; the gates are s of the chosen experts.  Either way
+    the gates are normalized to sum 1, then scaled by
+    ``routed_scaling_factor``.
+    """
+    logits = layers.dense(
+        x.astype(jnp.float32), p["router"].astype(jnp.float32), cfg,
+        layers.site_key(key, "moe_router"), site="moe_router",
+        precision=jax.lax.Precision.HIGHEST)                  # (b, s, e)
+    if cfg.router == "noaux_tc":
+        scores = jax.nn.sigmoid(logits)
+        _, eidx = jax.lax.top_k(scores + p["router_bias"], cfg.top_k)
+        gates = jnp.take_along_axis(scores, eidx, axis=-1)
+        eps = 1e-20
+    else:
+        gates, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                    cfg.top_k)
+        eps = 1e-9
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), eps)
+    return gates * cfg.routed_scaling_factor, eidx
 
 
 def moe_ffn(x, p, cfg, key=None, constrain=None):
@@ -56,19 +100,48 @@ def moe_ffn(x, p, cfg, key=None, constrain=None):
     GATHERED through the same token->slot dispatch as ``x``, so a token's
     expert matmuls draw from its own (request, position) key whatever
     slot it lands in — MoE outputs stay invariant to batch composition,
-    chunking, and eviction/resume like every other site.
+    chunking, and eviction/resume like every other site.  Shared experts
+    see every token once.
     """
+    with jax.named_scope("moe_route"):
+        gates, eidx = route(x, p, cfg, key)
+    with jax.named_scope("moe_experts"):
+        if cfg.moe_dropless and cfg.sc_backend == "exact":
+            out = _grouped_experts(x, p, gates, eidx)
+        else:
+            out = _capacity_experts(x, p, cfg, gates, eidx, key, constrain)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + layers.mlp(x, p["shared"], cfg, key)
+    return out
+
+
+def _grouped_experts(x, p, gates, eidx):
+    """Dropless dispatch: every (token, expert) pair of the step, sorted
+    by expert, through one ``moe_gmm`` call per projection; the pairs
+    return to token order and are summed under their gates."""
+    b, s, d = x.shape
+    k = eidx.shape[-1]
+    flat_e = eidx.reshape(-1)                                  # (T·k,)
+    order = jnp.argsort(flat_e, stable=True)
+    sizes = jnp.zeros((p["wi"].shape[0],), jnp.int32).at[flat_e].add(1)
+    xs = x.reshape(b * s, d)[order // k]                       # (T·k, d)
+    h = moe_gmm.moe_gmm(xs, p["wi"], sizes)
+    gate, up = jnp.split(h, 2, axis=-1)
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+    y = moe_gmm.moe_gmm(act, p["wo"], sizes)                   # (T·k, d)
+    y = jnp.zeros_like(y).at[order].set(y).reshape(b * s, k, d)
+    out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32),
+                     gates.reshape(b * s, k))
+    return out.reshape(b, s, d).astype(x.dtype)
+
+
+def _capacity_experts(x, p, cfg, gates, eidx, key, constrain):
+    """Capacity-buffer dispatch per batch row (module notes)."""
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     cap = capacity(cfg, s)
     cst = constrain or (lambda v, *a: v)
-
-    router_logits = layers.dense(
-        x.astype(jnp.float32), p["router"].astype(jnp.float32), cfg,
-        layers.site_key(key, "moe_router"), site="moe_router")   # (b, s, e)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gates, eidx = jax.lax.top_k(probs, k)                      # (b, s, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     # --- per-row capacity assignment via local sort --------------------
     flat_e = eidx.reshape(b, s * k)                            # (b, sk)
@@ -111,11 +184,7 @@ def moe_ffn(x, p, cfg, key=None, constrain=None):
     gate_sorted = jnp.take_along_axis(gates.reshape(b, s * k), order, axis=-1)
     ytk = ytk * gate_sorted[..., None].astype(ytk.dtype)
     out = jnp.zeros((b, s, d), x.dtype)
-    out = jax.vmap(lambda ob, tk, yv: ob.at[tk].add(yv))(out, token, ytk)
-
-    if cfg.shared_expert:
-        out = out + layers.mlp(x, p["shared"], cfg, key)
-    return out
+    return jax.vmap(lambda ob, tk, yv: ob.at[tk].add(yv))(out, token, ytk)
 
 
 def load_balancing_loss(router_probs, eidx, n_experts: int):

@@ -215,7 +215,7 @@ def ssm_block(x, p, cfg, key=None, *, cache=None, constrain=None):
     y = y.reshape(b, s, cfg.d_inner).astype(x.dtype)
     y = cst(y, "batch", "seq", "ssm_inner")
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)   # gate
-    y = layers.rms_norm(y, p["norm"])
+    y = layers.rms_norm(y, p["norm"], cfg.rms_norm_eps)
     okey = layers.site_key(key, "ssm_out")
     return layers.dense(y, p["out"], cfg, okey, site="ssm_out"), new_cache
 

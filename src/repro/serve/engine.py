@@ -528,6 +528,10 @@ class PagedServingEngine(_ArchTracedEngine):
                     f"speculative=True cannot rewind recurrent state, but "
                     f"family={cfg.family!r} carries SSM state — the verify "
                     "pass would advance it past rejected draft tokens")
+        if cfg.mla and getattr(cfg, "paged_attn", "unfused") == "fused_sc":
+            raise ValueError(
+                "paged_attn='fused_sc' has no latent-attention (MLA) form; "
+                "serve an MLA model with 'fused' or 'unfused'")
         self.params = params
         self.cfg = cfg
         self.scfg = scfg
@@ -561,6 +565,16 @@ class PagedServingEngine(_ArchTracedEngine):
         self._stochastic_substrate = (
             cfg.sc_backend != "exact" or fused_sc_attention)
         self._set_sc_ladder_gauge(cfg, fused_sc_attention)
+        self._set_cache_gauge()
+        # (token, expert) rows routed per fed position: top_k in each MoE
+        # layer (counted on the host from the plan, no device sync)
+        self._routed_per_position = (
+            cfg.top_k * lm.n_backbone_layers(cfg)
+            if cfg.family == "moe" else 0)
+        self._m_routed = self.metrics.counter(
+            "serve_moe_routed_rows_total",
+            "(token, expert) rows routed: fed positions x top_k x MoE "
+            "layers") if self._routed_per_position else None
         self._step_fn = _jit_step(cfg)
         self._sample_fn = jax.jit(_sample_rows)
         self._copy_fn = jax.jit(attention.paged_copy_blocks)
@@ -610,6 +624,20 @@ class PagedServingEngine(_ArchTracedEngine):
             "serve_decode_jit_ticks_total",
             "decode ticks excluded from the latency series (compile wall)")
         self._init_arch(collect_arch_trace, cfg)
+
+    def _set_cache_gauge(self) -> None:
+        """``serve_kv_bytes_per_position``: bytes the paged pool holds per
+        cached position, summed over every layer's pools (K and V, or a
+        latent row); engines with no paged layers leave it unset."""
+        if not self.cache_plan.has_paged:
+            return
+        # leaves are (layers, blocks, heads, block_size, width)
+        per = sum(n * heads * width * v.dtype.itemsize
+                  for v in jax.tree.leaves(attention.paged_leaves(self.pages))
+                  for n, _, heads, _, width in [v.shape])
+        self.metrics.gauge(
+            "serve_kv_bytes_per_position",
+            "paged cache bytes per cached position, all layers").set(per)
 
     # -- queue/active views mirroring the fixed-slot engine's attributes --
     @property
@@ -668,6 +696,8 @@ class PagedServingEngine(_ArchTracedEngine):
             tick.annotate(kind=kind, width=plan.sc, live=live, fed=plan.fed,
                           computed=plan.computed, refed=plan.refed)
             self._m_ticks.inc(kind=kind)
+            if self._m_routed is not None:
+                self._m_routed.inc(plan.fed * self._routed_per_position)
             if plan.copies:
                 # copy-on-write: a write this tick lands in a block that
                 # was shared/registered — carry its K/V to the fresh block
@@ -913,8 +943,8 @@ class PagedServingEngine(_ArchTracedEngine):
             from repro.serve.kv_cache import blocks_for
             nblk = blocks_for(seq.fed, self.scfg.block_size)
             ids = jnp.asarray(self.kv.tables[req.rid][:nblk], jnp.int32)
-            ckpt["kv"] = {"k": np.asarray(self.pages["k"][:, ids]),
-                          "v": np.asarray(self.pages["v"][:, ids])}
+            ckpt["kv"] = jax.tree.map(lambda v: np.asarray(v[:, ids]),
+                                      attention.paged_leaves(self.pages))
         return ckpt
 
     def restore(self, ckpt: dict) -> bool:
@@ -952,13 +982,9 @@ class PagedServingEngine(_ArchTracedEngine):
             return False
         nblk = blocks_for(fed, self.scfg.block_size)
         ids = jnp.asarray(self.kv.tables[req.rid][:nblk], jnp.int32)
-        self.pages = {
-            **self.pages,
-            "k": self.pages["k"].at[:, ids].set(
-                jnp.asarray(ckpt["kv"]["k"], self.pages["k"].dtype)),
-            "v": self.pages["v"].at[:, ids].set(
-                jnp.asarray(ckpt["kv"]["v"], self.pages["v"].dtype)),
-        }
+        self.pages = {**self.pages, **jax.tree.map(
+            lambda pool, v: pool.at[:, ids].set(jnp.asarray(v, pool.dtype)),
+            attention.paged_leaves(self.pages), ckpt["kv"])}
         seq = sched_mod.Sequence(req=req, key=req.key, fed=fed,
                                  pending=list(ckpt["pending"]),
                                  prefilling=ckpt["prefilling"])

@@ -93,8 +93,9 @@ class CachePlan:
 
     The paged engine serves every model family through one plan:
 
-    * attention (dense/moe/audio/vlm): every backbone layer owns a paged
-      K/V pool; ``state_layers = 0``.
+    * attention (dense/moe/audio/vlm): every layer — leading dense ones
+      included — owns a paged K/V pool (latent attention: one latent
+      pool); ``state_layers = 0``.
     * ssm: K/V pools don't exist — each backbone layer carries one O(1)
       state + conv-tail row PER BATCH ROW, indexed by slot (not by block
       table).  The block allocator still meters the admission/eviction
@@ -122,7 +123,7 @@ class CachePlan:
             return cls(cfg.family, 0, n)
         if cfg.family == "hybrid":
             return cls(cfg.family, lm.n_shared_invocations(cfg), n)
-        return cls(cfg.family, n, 0)
+        return cls(cfg.family, n + cfg.first_k_dense, 0)
 
     @property
     def has_paged(self) -> bool:

@@ -333,7 +333,7 @@ def test_serve_engine_hook_requires_array_backend(key):
 
 
 def test_dense_workload_covers_families():
-    for arch_id in ("paper-sc", "qwen3-14b", "moonshot-v1-16b-a3b",
+    for arch_id in ("paper-sc", "qwen3-14b", "moonlight-16b-a3b",
                     "mamba2-370m", "zamba2-7b"):
         cfg = get_smoke_config(arch_id)
         sites = arch.dense_workload(cfg, tokens=16)

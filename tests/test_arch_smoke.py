@@ -62,7 +62,7 @@ def test_full_config_matches_assignment(arch):
     cfg = get_config(arch)
     expected = {
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 8192, 202048),
         "chameleon-34b": (48, 8192, 64, 8, 22016, 65536),
         "starcoder2-15b": (40, 6144, 48, 4, 24576, 49152),
@@ -78,8 +78,14 @@ def test_full_config_matches_assignment(arch):
     nl, d, h, kv, ff, v = expected[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
             cfg.d_ff, cfg.vocab) == (nl, d, h, kv, ff, v)
-    if arch == "moonshot-v1-16b-a3b":
-        assert (cfg.n_experts, cfg.top_k) == (64, 6)
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.n_experts, cfg.top_k, cfg.moe_d_ff) == (64, 6, 1408)
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert (cfg.first_k_dense, cfg.n_shared_experts) == (1, 2)
+        assert cfg.router == "noaux_tc"
+        assert cfg.routed_scaling_factor == 2.446 and cfg.moe_dropless
+        assert (cfg.rope_theta, cfg.rms_norm_eps) == (50000.0, 1e-5)
     if arch == "llama4-maverick-400b-a17b":
         assert (cfg.n_experts, cfg.top_k) == (128, 1)
     if arch == "zamba2-7b":
@@ -95,7 +101,7 @@ def test_full_config_matches_assignment(arch):
 def test_smoke_decode_and_prefill_all_archs(key):
     """Prefill then decode for a couple of representative archs of each
     family; logits finite and cache threads correctly."""
-    for arch in ("qwen2-0.5b", "moonshot-v1-16b-a3b", "zamba2-7b",
+    for arch in ("qwen2-0.5b", "moonlight-16b-a3b", "zamba2-7b",
                  "mamba2-370m"):
         cfg = get_smoke_config(arch).replace(param_dtype=jnp.float32,
                                              act_dtype=jnp.float32)

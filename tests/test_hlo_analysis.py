@@ -127,13 +127,12 @@ def test_param_counts():
     cfg = get_config("yi-6b")
     n = H.param_count(cfg)
     assert 5.5e9 < n < 7.0e9          # "yi-6b"
-    moe = get_config("moonshot-v1-16b-a3b")
+    moe = get_config("moonlight-16b-a3b")
     total, active = H.param_count(moe), H.active_param_count(moe)
-    # NOTE: the ASSIGNED hyperparameters (48L x 64e x d_ff=1408) yield ~28B
-    # total — larger than the model card's name tag; the assignment's
-    # numbers govern. Active ~3.6B matches the "a3b" tag.
-    assert 20e9 < total < 32e9
-    assert 2e9 < active < 4.5e9       # "a3b"
+    # the published config: 671M embed + head, one dense layer (83M), 26
+    # MoE layers of 64 x 8.65M routed + 17.3M shared + 13.8M latent attn
+    assert 15.5e9 < total < 16.5e9    # "16B"
+    assert 2.7e9 < active < 3.2e9     # "A3B": 6 of 64 routed experts
     assert active < total
 
 

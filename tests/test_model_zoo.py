@@ -21,6 +21,13 @@ def _cfg(arch, **kw):
     return get_smoke_config(arch).replace(**F32, **kw)
 
 
+def _moe_cfg(**kw):
+    """Softmax routing with capacity dispatch: llama4's block at top-2
+    with no shared expert (override with ``kw``)."""
+    return _cfg("llama4-maverick-400b-a17b",
+                **{"top_k": 2, "n_shared_experts": 0, **kw})
+
+
 def _inputs(key, cfg, s=S):
     if cfg.frontend == "embeddings":
         return jax.random.normal(key, (B, s, cfg.d_model), cfg.act_dtype)
@@ -120,8 +127,7 @@ def _moe_onehot_reference(x, p, cfg, cap):
 def test_moe_capacity_overflow_matches_onehot_reference(key):
     """Overflowing experts drop exactly the late arrivals the one-hot
     formulation drops, with renormalized gates — and drops DO occur."""
-    cfg = _cfg("moonshot-v1-16b-a3b", n_experts=2, top_k=1,
-               capacity_factor=0.25, shared_expert=False)
+    cfg = _moe_cfg(n_experts=2, top_k=1, capacity_factor=0.25)
     s = 32
     cap = moe.capacity(cfg, s)
     assert s * cfg.top_k > cap * cfg.n_experts / 2  # overflow is possible
@@ -137,7 +143,7 @@ def test_moe_capacity_overflow_matches_onehot_reference(key):
 def test_moe_no_overflow_matches_dense_mixture(key):
     """With capacity slack the MoE output equals the unconstrained
     mixture (every token reaches every chosen expert)."""
-    cfg = _cfg("moonshot-v1-16b-a3b", shared_expert=False)
+    cfg = _moe_cfg()
     s = 4                                     # s*k=8 <= cap=8 per expert
     p = P.init_params(key, moe.moe_specs(cfg), jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (2, s, cfg.d_model),
@@ -183,8 +189,18 @@ def test_moe_per_token_keys_follow_dispatch(key):
     """A (b, s, 2) per-token key buffer rides the token->slot scatter:
     the same token's expert matmuls draw identical bits whatever its
     batch neighbours are (the paged engine's invariance contract)."""
-    cfg = _cfg("moonshot-v1-16b-a3b", sc_backend="moment", sc_nbit=64,
-               shared_expert=False)
+    _check_per_token_keys(_moe_cfg(sc_backend="moment", sc_nbit=64), key)
+
+
+def test_moe_per_token_keys_follow_dropless_dispatch(key):
+    """The same contract for a dropless config (sigmoid router with its
+    bias, shared experts) on the SC substrate, whose grouped path is the
+    capacity dispatch sized so that nothing drops."""
+    _check_per_token_keys(
+        _cfg("moonlight-16b-a3b", sc_backend="moment", sc_nbit=64), key)
+
+
+def _check_per_token_keys(cfg, key):
     s = 3
     p = P.init_params(key, moe.moe_specs(cfg), jnp.float32)
     xa = jax.random.normal(jax.random.fold_in(key, 1), (1, s, cfg.d_model),

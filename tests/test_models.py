@@ -11,6 +11,10 @@ from repro.models import attention, layers, lm, moe, ssm
 from repro.models import params as P
 
 F32 = dict(param_dtype=jnp.float32, act_dtype=jnp.float32)
+# the generic testbed of softmax routing and capacity dispatch: llama4's
+# block at top-2 with no shared expert
+MOE_SMOKE = get_smoke_config("llama4-maverick-400b-a17b").replace(
+    top_k=2, n_shared_experts=0)
 
 
 # ---------------------------------------------------------------------------
@@ -176,8 +180,7 @@ def test_hybrid_prefill_then_decode_matches_full_pass(key):
 def test_moe_matches_dense_reference(key):
     """Capacity-buffer dispatch == direct per-token expert evaluation when
     capacity is not exceeded."""
-    cfg = get_smoke_config("moonshot-v1-16b-a3b").replace(
-        capacity_factor=8.0, **F32)   # capacity ample -> no drops
+    cfg = MOE_SMOKE.replace(capacity_factor=8.0, **F32)  # no drops
     p = P.init_params(key, moe.moe_specs(cfg), jnp.float32)
     x = jax.random.normal(key, (2, 16, cfg.d_model), jnp.float32)
     out = moe.moe_ffn(x, p, cfg)
@@ -194,7 +197,7 @@ def test_moe_matches_dense_reference(key):
     onehot = jax.nn.one_hot(eidx, cfg.n_experts)             # (b,s,k,e)
     w = (gates[..., None] * onehot).sum(2)                   # (b,s,e)
     ref_out = jnp.einsum("bse,besd->bsd", w, y_all)
-    if cfg.shared_expert:
+    if cfg.n_shared_experts:
         ref_out = ref_out + layers.mlp(x, p["shared"], cfg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
                                rtol=2e-3, atol=2e-3)
@@ -203,8 +206,7 @@ def test_moe_matches_dense_reference(key):
 def test_moe_capacity_drops_tokens(key):
     """With capacity_factor << 1 overflow tokens are dropped (output 0
     contribution) instead of corrupting other slots."""
-    cfg = get_smoke_config("moonshot-v1-16b-a3b").replace(
-        capacity_factor=0.01, **F32)
+    cfg = MOE_SMOKE.replace(capacity_factor=0.01, **F32)
     p = P.init_params(key, moe.moe_specs(cfg), jnp.float32)
     x = jax.random.normal(key, (1, 32, cfg.d_model), jnp.float32)
     out = moe.moe_ffn(x, p, cfg)

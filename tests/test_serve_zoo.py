@@ -16,7 +16,7 @@ from repro.serve import (PagedServeConfig, PagedServingEngine, Request,
 from repro.serve.kv_cache import CachePlan
 
 F32 = dict(param_dtype=jnp.float32, act_dtype=jnp.float32)
-FAMILY_ARCHS = ["moonshot-v1-16b-a3b", "mamba2-370m", "zamba2-7b"]
+FAMILY_ARCHS = ["moonlight-16b-a3b", "mamba2-370m", "zamba2-7b"]
 
 
 def _cfg(arch, **kw):
@@ -56,7 +56,7 @@ def test_cache_plan_per_family():
              ["qwen2-0.5b"] + FAMILY_ARCHS}
     assert plans["qwen2-0.5b"].has_paged
     assert not plans["qwen2-0.5b"].has_state
-    assert plans["moonshot-v1-16b-a3b"].has_paged       # MoE pages like dense
+    assert plans["moonlight-16b-a3b"].has_paged         # MoE pages like dense
     assert plans["mamba2-370m"].has_state
     assert not plans["mamba2-370m"].has_paged
     hz = plans["zamba2-7b"]

@@ -163,3 +163,30 @@ def test_decode_paged_exact_step_compiles(chip):
     # the step fits one chip's 16 GB with room for the engine around it
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
+
+
+# Moonlight-16B-A3B's widths: 16 heads over one 576-wide latent row (512
+# of it the values), 64 experts of width 1408 at d_model 2048
+MLA_HEADS, MLA_R, MLA_ROPE = 16, 512, 64
+
+
+@pytest.mark.parametrize("width", [1, 16])
+def test_mla_paged_attention_compiles(chip, width):
+    q = _spec(chip, (SLOTS, width, MLA_HEADS, MLA_R + MLA_ROPE),
+              jnp.bfloat16)
+    pool = _spec(chip, (POOL, 1, BLOCK, MLA_R + MLA_ROPE), jnp.bfloat16)
+    text = _compiled_text(
+        lambda *a: pa.mla_paged_attention(*a, dv=MLA_R, scale=192 ** -0.5),
+        q, pool, _spec(chip, (SLOTS, NB), jnp.int32),
+        _spec(chip, (SLOTS,), jnp.int32))
+    assert "%mla_paged_attention." in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 2 * 1408), (1408, 2048)])
+def test_moe_gmm_compiles(chip, k, n):
+    from repro.kernels import moe_gmm
+    rows = 64 * 6                         # a decode tick's (token, expert)
+    text = _compiled_text(
+        moe_gmm.moe_gmm, _spec(chip, (rows, k), jnp.bfloat16),
+        _spec(chip, (64, k, n), jnp.bfloat16), _spec(chip, (64,), jnp.int32))
+    assert "%moe_gmm." in text
