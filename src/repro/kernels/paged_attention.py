@@ -24,10 +24,16 @@ per layer, flash-attention style:
   agree to float tolerance and greedy-decoded tokens are identical
   (``tests/test_paged_attention.py``).
 
-:func:`mla_paged_attention` runs latent attention's absorbed form on the
-same grid: one latent pool row per position serves every head (kvh = 1,
-a query group of all heads), its lanes are the keys and its first
-``kv_lora_rank`` lanes the values, and the scale is passed in.
+:func:`mla_paged_attention` runs latent attention's absorbed form on its
+own launcher, :func:`_launch_spans`: one latent pool row per position
+serves every head (kvh = 1, a query group of all heads), its lanes are
+the keys and its first ``kv_lora_rank`` lanes the values, and the scale
+is passed in.  Each grid step reads a span of :func:`span_pages` pages
+(128 positions, a lane-dense logits tile) and the walk stops at each
+row's last live position, ``lengths[r] + sc - 1``: past it every page
+index clamps to the last live page, so no DMA is issued and no compute
+runs.  The GQA and SC kernels keep :func:`_launch`'s one page a step
+over all of ``max_len``.
 
 The SC variant (:func:`paged_attention_fused_sc`) replaces the exact
 QK^T with the paper's stochastic MUL: operands quantize onto the DTC
@@ -60,6 +66,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -167,45 +174,60 @@ def _paged_attn_kernel(
         _emit(o_ref, d_ref, a_ref)
 
 
-def _mla_attn_kernel(
+def _last_live(length, sc: int):
+    """A row's last live kv position: chunk row ``sc - 1`` sits at
+    ``length + sc - 1`` and :func:`_mask` keeps every ``t`` up to it."""
+    return length + sc - 1
+
+
+def _mla_span_kernel(
     bt_ref,
     len_ref,
     q_ref,
-    kv_ref,
-    o_ref,
-    m_ref,
-    d_ref,
-    a_ref,
-    *,
+    *refs,
     sc: int,
     block_size: int,
-    nb: int,
+    span: int,
+    n_spans: int,
     block_q: int,
     scale: float,
     dv: int,
 ):
-    """Absorbed-MLA step: one latent page is both the keys (all its
-    lanes) and the values (its first ``dv`` lanes)."""
-    del bt_ref  # consumed by the page index map
+    """Absorbed-MLA span step: ``span`` latent pages, stacked in table
+    order, are the keys (all their lanes) and the values (their first
+    ``dv`` lanes) of one (block_q, span * block_size) logits tile.  Spans
+    that start past the row's last live position run nothing."""
+    del bt_ref  # consumed by the page index maps
+    pages, (o_ref, m_ref, d_ref, a_ref) = refs[:span], refs[span:]
     j = pl.program_id(3)
+    length = len_ref[pl.program_id(0)]
+    positions = span * block_size
+    # _mask's predicate, t <= lengths[r] + i, with the query positions
+    # taken out here: the interpreter reads no program id inside pl.when
+    row = pl.program_id(2) * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    q_pos = length + row % sc
 
     @pl.when(j == 0)
     def _():
         _init_carry(m_ref, d_ref, a_ref)
 
-    # scores on the operands' own dtype: bfloat16 products are exact in
-    # float32, so this equals an upcast at HIGHEST in one MXU pass
-    kv_blk = kv_ref[0, 0]  # (bs, dk)
-    exact = _HIGHEST if kv_blk.dtype == jnp.float32 else None
-    logits = jax.lax.dot_general(
-        q_ref[0, 0], kv_blk, (((1,), (1,)), ((), ())), precision=exact,
-        preferred_element_type=jnp.float32) * scale
-    logits = _mask(logits, len_ref[pl.program_id(0)], j=j, sc=sc,
-                   block_size=block_size, block_q=block_q)
-    _online_softmax_step(logits, kv_blk[:, :dv].astype(jnp.float32), m_ref,
-                         d_ref, a_ref)
+    @pl.when(j * positions <= _last_live(length, sc))
+    def _():
+        kv = jnp.concatenate([pg[0, 0] for pg in pages], axis=0)
+        # scores on the operands' own dtype: bfloat16 products are exact
+        # in float32, so this equals an upcast at HIGHEST in one MXU pass
+        exact = _HIGHEST if kv.dtype == jnp.float32 else None
+        logits = jax.lax.dot_general(
+            q_ref[0, 0], kv, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32) * scale
+        t_idx = j * positions + jax.lax.broadcasted_iota(
+            jnp.int32, (1, positions), 1)
+        logits = jnp.where(t_idx <= q_pos, logits, NEG_INF)
+        _online_softmax_step(logits, kv[:, :dv].astype(jnp.float32), m_ref,
+                             d_ref, a_ref)
 
-    @pl.when(j == nb - 1)
+    @pl.when(j == n_spans - 1)
     def _():
         _emit(o_ref, d_ref, a_ref)
 
@@ -400,6 +422,94 @@ def _launch(kernel, *, name, q_rows, block_table, lengths, extra, pools,
       q_rows, *extra, *pools)
 
 
+SPAN_POSITIONS = 128  # lanes of one logits tile
+_SPAN_VMEM = 4 << 20  # double-buffered span pages + the step's f32 tiles
+
+
+def span_pages(block_size: int, width: int, itemsize: int, block_q: int,
+               nb: int) -> int:
+    """Pages one grid step of :func:`_launch_spans` reads: enough for
+    :data:`SPAN_POSITIONS` positions, no more than the table's ``nb``,
+    and halved while the pages (double-buffered, ``width`` lanes of
+    ``itemsize`` bytes) and the (block_q, positions) float32 logits and
+    probabilities would pass the VMEM budget."""
+    span = max(1, min(SPAN_POSITIONS // block_size, nb))
+
+    def vmem(s):
+        return s * block_size * (2 * width * itemsize + 2 * block_q * 4)
+
+    while span > 1 and vmem(span) > _SPAN_VMEM:
+        span //= 2
+    return span
+
+
+def pages_walked(lengths, sc: int, block_size: int, nb: int) -> int:
+    """Pages one layer's :func:`_launch_spans` walk reads, on the host:
+    row ``r`` reads its table through its last live page, ``min(nb,
+    ceil((lengths[r] + sc) / block_size))`` pages, and skips the rest."""
+    live = -(-(np.asarray(lengths, np.int64) + sc) // block_size)
+    return int(np.minimum(live, nb).sum())
+
+
+def _launch_spans(kernel, *, name, q_rows, block_table, lengths, pools,
+                  span, sc, block_q, out_width=None):
+    """One fused-attention ``pallas_call`` over grid (b, kv, q tiles,
+    spans of ``span`` pages), named ``name``; the walk stops at each
+    row's fill.
+
+    q_rows: (b, kvh, rows_p, hd) kernel rows; pools: the (P, kvh, bs, w)
+    page pools, each passed ``span`` times so that Pallas pipelines one
+    page slot per operand.  Slot ``s`` of span ``j`` reads table entry
+    ``min(j * span + s, last)``, ``last`` the row's last live page
+    (:func:`_last_live`, at most ``nb - 1``): a slot past the fill
+    repeats the last live page, and a step whose pages all repeat the
+    previous step's issues no DMA.  The kernel gets, after the query
+    tile, ``span`` page refs per pool in pool order, the output and the
+    (max, denominator, accumulator) scratch, ``out_width`` wide
+    (default: the query width).
+    """
+    b, kvh, rows_p, hd = q_rows.shape
+    bs = pools[0].shape[2]
+    nb = block_table.shape[1]
+    dv = out_width or hd
+
+    def page(s):
+        def index(i, h_, qi, j, bt, ln):
+            last = jnp.minimum(jax.lax.div(_last_live(ln[i], sc), bs),
+                               nb - 1)
+            return (bt[i * nb + jnp.minimum(j * span + s, last)], h_, 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, kvh, rows_p // block_q, -(-nb // span)),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, hd),
+                         lambda i, h_, qi, j, bt, ln: (i, h_, qi, 0)),
+            *[pl.BlockSpec((1, 1, bs, pool.shape[-1]), page(s))
+              for pool in pools for s in range(span)],
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
+                               lambda i, h_, qi, j, bt, ln: (i, h_, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows_p, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=kernels.interpret_mode(),
+        name=name,
+    )(block_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      q_rows, *[pool for pool in pools for _ in range(span)])
+
+
 def _row_tile(block_q: int) -> int:
     """Query rows per grid step, rounded up to a sublane multiple."""
     return -(-max(1, block_q) // 8) * 8
@@ -455,8 +565,10 @@ def mla_paged_attention(q, pool, block_table, lengths, *, dv: int,
     latent "KV head": scores are ``q·page`` times ``scale`` (the
     decompressed head's 1/sqrt(qk width), not 1/sqrt(dk)), values the
     first ``dv`` lanes of the same page.  Returns the latent outputs
-    (b, sc, h, dv), which the caller takes up through W_UV.  Same grid,
-    page walk and masking as the GQA kernel (``_launch``).
+    (b, sc, h, dv), which the caller takes up through W_UV.  Same
+    masking as the GQA kernel; the walk reads :func:`span_pages` pages a
+    grid step and stops at each row's fill (:func:`_launch_spans`), so
+    table entries past a row's last live page are never read.
     """
     b, sc, h, dk = q.shape
     bs = pool.shape[2]
@@ -465,16 +577,18 @@ def mla_paged_attention(q, pool, block_table, lengths, *, dv: int,
     if block_q <= 0:
         block_q = autotune.get_attn_tile(rows, bs, dk, 0).block_q
     block_q = _row_tile(block_q)
+    span = span_pages(bs, dk, pool.dtype.itemsize, block_q, nb)
     qr = _rows_layout(q, 1)
     pad = (-rows) % block_q
     if pad:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kernel = functools.partial(
-        _mla_attn_kernel, sc=sc, block_size=bs, nb=nb, block_q=block_q,
-        scale=float(scale), dv=dv)
-    out = _launch(kernel, name="mla_paged_attention", q_rows=qr,
-                  block_table=block_table, lengths=lengths, extra=(),
-                  pools=(pool,), block_q=block_q, out_width=dv)
+        _mla_span_kernel, sc=sc, block_size=bs, span=span,
+        n_spans=-(-nb // span), block_q=block_q, scale=float(scale), dv=dv)
+    out = _launch_spans(kernel, name="mla_paged_attention", q_rows=qr,
+                        block_table=block_table, lengths=lengths,
+                        pools=(pool,), span=span, sc=sc, block_q=block_q,
+                        out_width=dv)
     return _rows_unlayout(out[:, :, :rows], sc=sc, h=h).astype(q.dtype)
 
 

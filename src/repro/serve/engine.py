@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels import paged_attention
 from repro.models import attention, lm
 
 # Depth of serve.api.build_engine construction scopes on this thread.
@@ -575,6 +576,19 @@ class PagedServingEngine(_ArchTracedEngine):
             "serve_moe_routed_rows_total",
             "(token, expert) rows routed: fed positions x top_k x MoE "
             "layers") if self._routed_per_position else None
+        # latent pages the fused MLA walk reads and skips, every MLA layer
+        # (counted on the host from the plan, no device sync)
+        self._mla_layers = (cfg.n_layers if cfg.mla and getattr(
+            cfg, "paged_attn", "unfused") == "fused" else 0)
+        if self._mla_layers:
+            self._m_mla_walked = self.metrics.counter(
+                "serve_mla_pages_walked_total",
+                "latent pages the fused MLA kernel reads: per row through "
+                "its last live page, x MLA layers")
+            self._m_mla_skipped = self.metrics.counter(
+                "serve_mla_pages_skipped_total",
+                "latent table pages past each row's fill the fused MLA "
+                "kernel skips, x MLA layers")
         self._step_fn = _jit_step(cfg)
         self._sample_fn = jax.jit(_sample_rows)
         self._copy_fn = jax.jit(attention.paged_copy_blocks)
@@ -698,6 +712,8 @@ class PagedServingEngine(_ArchTracedEngine):
             self._m_ticks.inc(kind=kind)
             if self._m_routed is not None:
                 self._m_routed.inc(plan.fed * self._routed_per_position)
+            if self._mla_layers:
+                self._count_mla_pages(plan, spec)
             if plan.copies:
                 # copy-on-write: a write this tick lands in a block that
                 # was shared/registered — carry its K/V to the fresh block
@@ -712,6 +728,16 @@ class PagedServingEngine(_ArchTracedEngine):
                 self._run_plan(plan, live)
             self.ticks += 1
             return True
+
+    def _count_mla_pages(self, plan, spec: bool) -> None:
+        # a speculative tick's fused call is the width-(k+1) verify pass
+        width = self.scheduler.spec_k + 1 if spec else plan.sc
+        nb = self.kv.cfg.blocks_per_seq
+        walked = paged_attention.pages_walked(
+            plan.lengths, width, self.scfg.block_size, nb)
+        self._m_mla_walked.inc(walked * self._mla_layers)
+        self._m_mla_skipped.inc(
+            (len(plan.lengths) * nb - walked) * self._mla_layers)
 
     def _run_plan(self, plan, live: int):
         tr = self.tracer

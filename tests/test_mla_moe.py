@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.kernels import paged_attention as pa
 from repro.models import attention, layers, lm, moe
 from repro.models import params as P
 from repro.models import reference_mla_moe as ref
@@ -138,6 +139,79 @@ def test_engine_chunked_prefill_then_decode_matches_reference(mode):
         low = ref.logits(params, ref.hidden(params, toks, c, "bf16")[at], c,
                          "bf16")
         _departs(low, want, scale)
+
+
+BS, NB = 16, 12     # 8 pages a span: 12 is not a multiple of it
+FILLS = {"zero": lambda sc, nb: 0, "inside": lambda sc, nb: BS + 5,
+         "boundary": lambda sc, nb: 2 * BS, "full": lambda sc, nb: nb * BS - sc}
+
+
+@pytest.mark.parametrize("nb", [8, NB])
+@pytest.mark.parametrize("sc", [1, 16])
+@pytest.mark.parametrize("fill", sorted(FILLS))
+def test_mla_walk_never_reads_past_fill(fill, sc, nb):
+    """Table entries past each row's last live page name NaN pages, the
+    last span's spare slots among them: the kernel still equals gather +
+    chunk_decode_attention over the clean pool, so it read none of them
+    (a masked NaN value would still enter p·v)."""
+    rng = np.random.default_rng(sc * nb)
+    b, h, r, rope = 2, 2, 16, 8
+    dk = r + rope
+    pool = jnp.asarray(rng.normal(size=(1 + b * nb, 1, BS, dk)), jnp.float32)
+    bt = jnp.asarray(1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, sc, h, dk)), jnp.float32)
+    lengths = jnp.asarray([FILLS[fill](sc, nb), 37], jnp.int32)
+    assert pa.span_pages(BS, dk, 4, 8 * (-(-h * sc // 8)), nb) == 8
+    dead = np.asarray(bt)[np.arange(nb)[None, :] > np.minimum(
+        (np.asarray(lengths) + sc - 1) // BS, nb - 1)[:, None]]
+    assert dead.size                    # row 1 leaves pages past its fill
+    poisoned = pool.at[dead].set(jnp.nan)
+    kc = attention.paged_gather(pool, bt)
+    want = attention.chunk_decode_attention(q, kc, kc[..., :r], lengths,
+                                            0.3)
+    got = pa.mla_paged_attention(q, poisoned, bt, lengths, dv=r, scale=0.3)
+    assert np.isfinite(np.asarray(got)).all()
+    _close(got, want, f"fill {fill}")
+
+
+@pytest.mark.parametrize("block_size,nb,width,itemsize,want", [
+    (16, 128, 576, 2, 8),       # Moonlight: 128 positions a step
+    (8, 256, 576, 2, 16),
+    (32, 64, 576, 2, 4),
+    (128, 16, 576, 2, 1),
+    (256, 8, 576, 2, 1),        # a page wider than the tile: one a step
+    (16, 3, 576, 2, 3),         # no more than the table holds
+    (16, 128, 8192, 4, 2),      # halved to the VMEM budget
+])
+def test_span_pages_rule(block_size, nb, width, itemsize, want):
+    assert pa.span_pages(block_size, width, itemsize, 256, nb) == want
+
+
+def test_pages_walked_counts_through_last_live_page():
+    # block 4, table 8: fills 0, 5 and 30 at width 3 end at positions 2,
+    # 7 and 32 — pages 1, 2 and (past the table) all 8
+    assert pa.pages_walked([0, 5, 30], 3, 4, 8) == 1 + 2 + 8
+    assert pa.pages_walked([4, 3], 1, 4, 8) == 2 + 1
+
+
+def test_engine_counts_mla_pages_walked_and_skipped():
+    """A prompt of 5 in chunks of 3 beside an empty slot, then one
+    decode; block 4, table 8, 3 latent layers.  Ticks (lengths, width):
+    ([0, 0], 3) reads 1 + 1 pages, ([3, 0], 3) 2 + 1, ([5, 0], 1) 2 + 1."""
+    cfg = _cfg(paged_attn="fused")
+    eng = build_engine(_params(cfg), cfg, ServeOptions(
+        paged=True, slots=2, max_len=32, block_size=4, prefill_chunk=3,
+        eos_id=-1))
+    eng.submit(Request(rid=0, prompt=[5, 9, 17, 3, 8], max_new_tokens=2))
+    eng.run_until_drained()
+    assert eng.ticks == 3 and cfg.n_layers == 3
+    assert eng.metrics.value("serve_mla_pages_walked_total") == 8 * 3
+    assert eng.metrics.value("serve_mla_pages_skipped_total") == (
+        3 * 2 * 8 - 8) * 3
+    unfused = _cfg(paged_attn="unfused")
+    eng = build_engine(_params(unfused), unfused, ServeOptions(
+        paged=True, slots=1, max_len=16, block_size=4, prefill_chunk=4))
+    assert eng.metrics.value("serve_mla_pages_walked_total") is None
 
 
 def test_forward_equals_reference():
